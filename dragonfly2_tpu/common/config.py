@@ -176,12 +176,11 @@ def _scalar(s: str) -> Any:
 
 
 # DF_* vars that are NOT config-field overrides (consumed elsewhere:
-# dfpath default, tpu.topology injection + probe timeout). Missing an
-# entry here is fatal at boot — the launcher folds every other DF_* var
-# into the config tree and unknown keys are errors by design.
+# dfpath default, tpu.topology injection). Missing an entry here is fatal
+# at boot — the launcher folds every other DF_* var into the config tree
+# and unknown keys are errors by design.
 _ENV_NON_CONFIG = {"DF_WORKDIR", "DF_ZONE", "DF_DEFAULT_ZONE",
-                   "DF_ICI_COORDS", "DF_POD_ID",
-                   "DF_TOPOLOGY_PROBE_TIMEOUT_S"}
+                   "DF_ICI_COORDS", "DF_POD_ID"}
 
 
 def env_overrides(prefix: str = "DF_") -> dict[str, Any]:

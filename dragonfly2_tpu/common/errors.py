@@ -41,6 +41,7 @@ class Code(enum.IntEnum):
     CLIENT_CONTEXT_CANCELED = 3003
     CLIENT_DIGEST_MISMATCH = 3004
     CLIENT_STORAGE_ERROR = 3005
+    CLIENT_DEVICE_SINK_ERROR = 3006  # a requested device sink was refused or lost
 
     # origin
     SOURCE_ERROR = 4000

@@ -59,6 +59,9 @@ _shard_bytes = REGISTRY.counter(
     "df_shard_bytes_total", "bytes landed into manifest shards, by the "
     "piece's supply class", ("src",))
 
+# bound on waiting out a finished download's last device transfers
+SINK_DRAIN_TIMEOUT_S = 600.0
+
 
 class PeerTaskConductor:
     # terminal states
@@ -164,6 +167,11 @@ class PeerTaskConductor:
         self.qos_release: Any = None
         self.storage: TaskStorage | None = None
         self.device_ingest: Any = None
+        # why a requested device sink is gone (refused at open, a failed
+        # write/flush/transfer): the bytes still finish landing on disk —
+        # the swarm can be fed from them and a retry re-stages without
+        # the wire — but the task ends FAILED with this reason
+        self.sink_error = ""
         self.ready: set[int] = set()          # piece numbers landed
         self._landing: set[int] = set()       # pieces mid-write (dedup race)
         self.done_event = asyncio.Event()
@@ -292,21 +300,42 @@ class PeerTaskConductor:
             self.log.warning("scheduler unreachable (%s); falling back", exc)
             return None
 
-    def _ingest_to_device(self, num: int, offset: int, data) -> None:
-        """Stage one piece into the device sink; a failure disables the
-        sink for the rest of the task (best-effort contract). The ONE
-        copy of the write/journal/disable sequence — landing, adoption,
-        and placement all stage through here."""
-        if self.device_ingest is None:
-            return
-        try:
-            self.device_ingest.write(offset, data)
-            if self.flight is not None:
-                self.flight.event(fr.HBM_DONE, num, nbytes=len(data))
-        except Exception:
-            self.log.exception("device ingest write failed; disabling sink")
+    def _sink_lost(self, why: str) -> None:
+        """The requested device sink is gone: remember why (the task will
+        end FAILED with it, _finish_sink) and release the sink."""
+        self.log.error("device sink lost: %s", why, exc_info=True)
+        if not self.sink_error:
+            self.sink_error = why
+        if self.device_ingest is not None:
             self.device_ingest.close()
             self.device_ingest = None
+
+    def _open_device_sink(self, content_length: int) -> None:
+        if (self.device_sink_factory is None or content_length <= 0
+                or self.device_ingest is not None or self.sink_error):
+            return
+        try:
+            self.device_ingest = self._make_device_ingest(content_length)
+        except Exception as exc:  # noqa: BLE001 - reported at finish
+            self._sink_lost(f"device sink refused: {type(exc).__name__}: "
+                            f"{exc}")
+
+    def _ingest_to_device(self, num: int, offset: int, data) -> bool:
+        """Stage one piece into the device sink; False once the sink is
+        lost. The ONE copy of the write/journal/loss sequence — every
+        landing path (pieces, spans, adoption, placement) stages through
+        here."""
+        if self.device_ingest is None:
+            return False
+        try:
+            self.device_ingest.write(offset, data)
+        except Exception as exc:  # noqa: BLE001 - reported at finish
+            self._sink_lost(f"device ingest write failed at piece {num}: "
+                            f"{type(exc).__name__}: {exc}")
+            return False
+        if self.flight is not None:
+            self.flight.event(fr.HBM_DONE, num, nbytes=len(data))
+        return True
 
     # ------------------------------------------------------------------
     # content-addressed dedupe (storage/castore.py)
@@ -341,14 +370,7 @@ class PeerTaskConductor:
         self.total_pieces = ts.md.total_piece_count
         self._init_shards()
         self.storage_mgr.castore.note_hit("content", ts.md.content_length)
-        if (self.device_sink_factory is not None
-                and self.content_length > 0 and self.device_ingest is None):
-            try:
-                self.device_ingest = self._make_device_ingest(
-                    self.content_length)
-            except Exception:  # device sink is best-effort
-                self.log.exception("device sink init failed; continuing "
-                                   "to disk")
+        self._open_device_sink(self.content_length)
         for num in sorted(ts.md.pieces):
             p = ts.md.pieces[num]
             if self.device_ingest is not None:
@@ -666,12 +688,7 @@ class PeerTaskConductor:
             self._relay_tracked = True
             self.relay.track(self.task_id, total_pieces=self.total_pieces,
                              on_open=self._on_relay_span)
-        if (self.device_sink_factory is not None and effective_len > 0
-                and self.device_ingest is None):
-            try:
-                self.device_ingest = self._make_device_ingest(effective_len)
-            except Exception:  # device sink is best-effort
-                self.log.exception("device sink init failed; continuing to disk")
+        self._open_device_sink(effective_len)
         return self.piece_size
 
     def _on_relay_span(self, span) -> None:
@@ -829,25 +846,15 @@ class PeerTaskConductor:
             try:
                 for n in placed:
                     p = by_num[n]
-                    try:
-                        if n in on_disk:
-                            # this span's copy of an already-recorded
-                            # piece was never digest-checked — stage the
-                            # VERIFIED bytes from disk instead
-                            src = await run_io(self.storage.read_piece, n)
-                            self.device_ingest.write(p.range_start, src)
-                        else:
-                            lo = p.range_start - base
-                            self.device_ingest.write(
-                                p.range_start, view[lo:lo + p.range_size])
-                        if self.flight is not None:
-                            self.flight.event(fr.HBM_DONE, n,
-                                              nbytes=p.range_size)
-                    except Exception:
-                        self.log.exception(
-                            "device ingest write failed; disabling sink")
-                        self.device_ingest.close()
-                        self.device_ingest = None
+                    if n in on_disk:
+                        # this span's copy of an already-recorded piece
+                        # was never digest-checked — stage the VERIFIED
+                        # bytes from disk instead
+                        staged = await run_io(self.storage.read_piece, n)
+                    else:
+                        lo = p.range_start - base
+                        staged = view[lo:lo + p.range_size]
+                    if not self._ingest_to_device(n, p.range_start, staged):
                         break
             finally:
                 view.release()
@@ -1040,25 +1047,8 @@ class PeerTaskConductor:
                 await run_io(self.storage.mark_done, success=True,
                              content_length=self.content_length,
                              total_piece_count=self.total_pieces)
-        if self.device_ingest is not None:
-            try:
-                self.device_ingest.flush()   # enqueue-only, non-blocking
-            except Exception:
-                self.log.exception("device sink flush failed")
-                self.device_ingest.close()
-                self.device_ingest = None
-        if self.device_ingest is not None:
-            # inside the peertask span context: the HBM landing joins the
-            # task's trace (schedule decision -> piece fetch -> HBM)
-            from ..common import tracing
-            spans = list(self.device_ingest.transfer_spans)
-            with tracing.span("hbm.ingest",
-                              task_id=self.task_id[:16]) as hsp:
-                hsp.set(transfers=len(spans),
-                        done_fraction=self.device_ingest.done_fraction(),
-                        dma_ms=round(sum(b - a for a, b in spans) * 1e3, 3))
-            if self.flight is not None:
-                self.flight.hbm_spans(spans)
+        if self.device_sink_factory is not None:
+            await self._finish_sink()
         self.state = self.SUCCESS
         if self.flight is not None:
             self.flight.finish(self.SUCCESS)
@@ -1078,6 +1068,35 @@ class PeerTaskConductor:
                       self.traffic_p2p, self.traffic_source,
                       self.traffic_placed)
 
+    async def _finish_sink(self) -> None:
+        """A task that asked for a device sink succeeds only with every
+        shard ON the device: wait out the last transfers (off-loop) and
+        raise, with the reason, when the sink was refused, lost on the
+        way, or a transfer failed."""
+        ingest = self.device_ingest
+        if ingest is not None:
+            try:
+                ingest.flush()
+                await asyncio.to_thread(ingest.drain, SINK_DRAIN_TIMEOUT_S)
+            except Exception as exc:  # noqa: BLE001 - becomes the verdict
+                cause = exc.__cause__ or exc
+                self._sink_lost(f"device transfer failed: "
+                                f"{type(cause).__name__}: {cause}")
+        if self.device_ingest is None:
+            raise DFError(Code.CLIENT_DEVICE_SINK_ERROR, self.sink_error or
+                          "no device sink was opened (content length "
+                          "unknown)")
+        # inside the peertask span context: the HBM landing joins the
+        # task's trace (schedule decision -> piece fetch -> HBM)
+        from ..common import tracing
+        spans = list(ingest.transfer_spans)
+        with tracing.span("hbm.ingest", task_id=self.task_id[:16]) as hsp:
+            hsp.set(transfers=len(spans),
+                    done_fraction=ingest.done_fraction(),
+                    dma_ms=round(sum(b - a for a, b in spans) * 1e3, 3))
+        if self.flight is not None:
+            self.flight.hbm_spans(spans)
+
     async def _finish_fail(self, code: Code, message: str) -> None:
         if self.state in (self.SUCCESS, self.FAILED):
             return
@@ -1088,13 +1107,15 @@ class PeerTaskConductor:
             # ladder exhausted: the fail rung makes the terminal verdict
             # part of the journal, not just the PeerResult code
             self.flight.rung(fr.RUNG_FAIL)
-            self.flight.finish(self.FAILED)
+            self.flight.finish(self.FAILED, message)
             from ..common.health import PLANE
             PLANE.slo.observe_summary(self.flight.summarize())
         if self.device_ingest is not None:
             self.device_ingest.close()
             self.device_ingest = None
-        if self.storage is not None:
+        if self.storage is not None and not self.sink_error:
+            # (a lost sink fails the REQUEST, not the bytes: the verified
+            # disk copy keeps the state _finish_success gave it)
             try:
                 await run_io(self.storage.mark_done, success=False)
             except Exception:  # noqa: BLE001

@@ -140,6 +140,10 @@ class Daemon:
         self.prober: Any = None
         self.manager: Any = None
         self.health: Any = None
+        # this host's jax devices; None until a device sink is first asked
+        # for (device_runtime) — the process holds no chip before that
+        self._devices: list | None = None
+        self._devices_lock = asyncio.Lock()
 
     # ------------------------------------------------------------------
 
@@ -158,42 +162,61 @@ class Daemon:
             # evidence (this daemon verified its own bit-rot)
             quarantined=self.verdicts.self_quarantined)
 
-    def device_sink_builder(self, spec: DeviceSink):
+    async def device_runtime(self) -> list:
+        """This host's devices, bringing JAX up on first use — the moment
+        this process takes the chip. Constructing or starting a daemon
+        never comes here; only a device-sink request does. The cold init
+        (8-13 s on a v5e) runs in a worker thread, once; one native call
+        inside it still holds the GIL for seconds (0.3-5.6 s seen on one
+        chip), so an embedding process that knows it will open sinks can
+        await this right after start() and pay that stall before traffic
+        does. What the devices
+        tell about the host's position rides the next announce and
+        register."""
+        if self._devices is None:
+            async with self._devices_lock:
+                if self._devices is None:
+                    from ..tpu import runtime
+                    try:
+                        devices = await asyncio.to_thread(runtime.bring_up)
+                    except Exception as exc:  # noqa: BLE001 - jax raises a zoo
+                        raise DFError(
+                            Code.CLIENT_DEVICE_SINK_ERROR,
+                            f"no device runtime: {type(exc).__name__}: "
+                            f"{exc}") from exc
+                    self.topology = topology.with_devices(self.topology,
+                                                          devices)
+                    if hasattr(self.scheduler, "refresh_host"):
+                        self.scheduler.refresh_host(self.host_info())
+                    self._devices = devices
+        return self._devices
+
+    async def device_sink_builder(self, spec: DeviceSink):
         """Returns a factory(content_length[, shard_specs]) -> DeviceIngest
-        honoring the request's sink spec. ``shard_specs`` (sharded tasks,
+        honoring the request's sink spec; raises when this process cannot
+        bring a device runtime up. ``shard_specs`` (sharded tasks,
         common/sharding.py) switches the sink to manifest mode: named
         uneven shards that each become a device array the moment their
         bytes are covered."""
-        def factory(content_length: int, shard_specs: list | None = None):
-            if not topology.ensure_runtime_alive():
-                # permanently poisoned (our own probe thread is parked in
-                # jax init holding its locks), host-marked wedged, or a
-                # fresh bounded probe just timed out: a bare jax call here
-                # would hang the EVENT LOOP, not just this task — refuse
-                # and let the caller fall back to disk-only. A recovered
-                # runtime is re-admitted by the bounded probe.
-                raise DFError(
-                    Code.UNAVAILABLE,
-                    "accelerator runtime is not answering; device sink "
-                    "unavailable")
-            import jax
+        devices = await self.device_runtime()
 
+        def factory(content_length: int, shard_specs: list | None = None):
             from ..tpu.hbm_sink import DeviceIngest
             if shard_specs:
                 return DeviceIngest(content_length, dtype=spec.dtype,
+                                    devices=devices,
                                     shard_specs=shard_specs)
             spd = spec.pipeline_shards
             if spd <= 0:
-                # auto: one shard per DMA unit. Measured on the real chip:
-                # smaller units lose (8 MiB ≈ serial, 16-per-file
-                # pathological); the overlap comes from back-source's
-                # front-to-back work-queue coverage completing these units
-                # progressively, not from shrinking them.
+                # auto: one shard per DMA unit (32 MiB; the unit size
+                # against smaller ones is not measured). The overlap comes
+                # from back-source's front-to-back work-queue coverage
+                # completing these units progressively.
                 from ..common.piece import INGEST_DMA_UNIT_BYTES
-                per_dev = -(-content_length // len(jax.devices()))
+                per_dev = -(-content_length // len(devices))
                 spd = max(1, min(32, per_dev // INGEST_DMA_UNIT_BYTES))
             return DeviceIngest(content_length, dtype=spec.dtype,
-                                shards_per_device=spd)
+                                devices=devices, shards_per_device=spd)
         return factory
 
     async def _enroll_security(self):

@@ -124,7 +124,7 @@ class TaskFlight:
     __slots__ = ("task_id", "peer_id", "started_at", "_m0", "events",
                  "serves", "state", "url", "report_drops", "_sum_key",
                  "_sum_cache", "qos_class", "tenant", "shards_total",
-                 "on_rung")
+                 "on_rung", "fail_reason")
 
     def __init__(self, task_id: str, peer_id: str, *, url: str = "",
                  max_events: int = 4096, max_serves: int = 1024,
@@ -147,6 +147,7 @@ class TaskFlight:
         # them.
         self.serves: deque = deque(maxlen=max_serves)
         self.state = "running"
+        self.fail_reason = ""      # the terminal verdict's message
         # piece reports dropped because the scheduler stream's writer died
         # (scheduler_session.report_piece) — a silent drop becomes a ghost
         # peer on the scheduler, so the count rides the flight summary
@@ -177,8 +178,9 @@ class TaskFlight:
             (self.now_ms() if t_ms is None else t_ms, stage, piece,
              parent, nbytes, dur_ms))
 
-    def finish(self, state: str) -> None:
+    def finish(self, state: str, reason: str = "") -> None:
         self.state = state
+        self.fail_reason = reason
         self.event(DONE)
 
     def rung(self, name: str) -> None:
@@ -396,6 +398,9 @@ class TaskFlight:
         summary = {
             "task_id": self.task_id, "peer_id": self.peer_id,
             "state": self.state,
+            # why a failed task failed (a lost device sink names itself
+            # here: the bytes may all have landed and the task still fail)
+            "fail_reason": self.fail_reason,
             "pieces": len(piece_rows),
             "bytes_p2p": sum(r["bytes"] for r in piece_rows
                              if r["source"] == "p2p"),

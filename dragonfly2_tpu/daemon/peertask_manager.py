@@ -250,6 +250,17 @@ class PeerTaskManager:
                     parent_id, rng.start, rng.length)
                 if reuse is None:
                     rng = None
+        # never plain success without the sink that was asked for
+        want_sink = req.device_sink is not None and req.device_sink.enabled
+        if want_sink and self.device_sink_builder is None:
+            raise DFError(Code.CLIENT_DEVICE_SINK_ERROR,
+                          "this daemon has no device sink")
+        if want_sink and reuse is not None:
+            # no download runs for content already on disk, so nothing
+            # would carry a sink (tpu/data.py stages it from storage)
+            raise DFError(Code.CLIENT_DEVICE_SINK_ERROR,
+                          "content already complete on disk: no download "
+                          "to carry the device sink")
         if reuse is not None:
             if req.output:
                 await asyncio.to_thread(
@@ -264,9 +275,10 @@ class PeerTaskManager:
             return
 
         device_factory = None
-        if req.device_sink is not None and req.device_sink.enabled \
-                and self.device_sink_builder is not None:
-            device_factory = self.device_sink_builder(req.device_sink)
+        if want_sink:
+            # brings the device runtime up (first time: seconds, off-loop)
+            # BEFORE any byte moves, so a host without one fails here
+            device_factory = await self.device_sink_builder(req.device_sink)
 
         conductor = await self.get_or_create_conductor(
             req.url, meta, task_type=req.task_type,
@@ -302,6 +314,11 @@ class PeerTaskManager:
                     if not event.get("success"):
                         raise DFError(Code(event.get("code") or Code.UNKNOWN),
                                       event.get("message", "download failed"))
+                    if want_sink and conductor.device_ingest is None:
+                        # joined a download that was started without one
+                        raise DFError(Code.CLIENT_DEVICE_SINK_ERROR,
+                                      "the download this request joined "
+                                      "carries no device sink")
                     if req.output:
                         assert conductor.storage is not None
                         await asyncio.to_thread(conductor.storage.store_to,

@@ -302,15 +302,11 @@ class Scheduler:
             self.cfg.manager_addresses,
             keepalive_interval_s=self.cfg.keepalive_interval_s)
         try:
-            # the JAX device probe can take seconds on a cold TPU runtime
-            # and touches its cache file — run it off-loop; kept INSIDE
-            # the try so a probe failure degrades to standalone mode like
-            # any other attach failure instead of aborting scheduler boot
-            topo = await asyncio.to_thread(topology.detect)
             await self.manager.register_scheduler(RegisterSchedulerRequest(
                 hostname=hostname, ip=self.cfg.advertise_ip, port=self.port,
                 scheduler_cluster_id=self.cfg.cluster_id,
-                topology=topo))
+                # environment only: a scheduler never takes the chip
+                topology=topology.detect()))
             self.manager.start_keepalive(source_type="scheduler",
                                          hostname=hostname,
                                          ip=self.cfg.advertise_ip,

@@ -212,6 +212,8 @@ def verdict(summary: dict) -> str:
             f"parent {addr} was locally QUARANTINED mid-task on corrupt "
             "verdicts (the verdict ledger shuns it for every task on "
             "this daemon; the scheduler's registry handles the pod)")
+    if summary.get("fail_reason"):
+        parts.append(f"the task FAILED: {summary['fail_reason']}")
     drops = summary.get("report_drops", 0)
     if drops:
         parts.append(f"{drops} piece reports dropped on a dead scheduler "

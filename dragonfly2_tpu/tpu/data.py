@@ -72,15 +72,11 @@ class ShardPrefetcher:
 
     SHARD_TIMEOUT_S = 600.0
 
-    async def _ingest_from_storage(self, task_id: str):
-        """Device leg for content already on disk: the task fast path
-        (completed-task reuse, e.g. epoch >= 2 with ``delete_after=False``)
-        returns no conductor/sink, so feed the stored pieces through a
-        fresh DeviceIngest."""
-        store = self.daemon.ptm.storage_mgr.find_completed_task(task_id)
-        if store is None:
-            return None
-        factory = self.daemon.device_sink_builder(
+    async def _ingest_from_storage(self, store):
+        """Device leg for content already on disk (completed-task reuse,
+        e.g. epoch >= 2 with ``delete_after=False``): no download runs, so
+        feed the stored pieces through a fresh DeviceIngest."""
+        factory = await self.daemon.device_sink_builder(
             DeviceSink(enabled=True, dtype=self.dtype))
         ingest = factory(store.md.content_length)
 
@@ -93,39 +89,33 @@ class ShardPrefetcher:
 
     async def _fetch(self, url: str):
         """One shard through the real daemon path; returns the device
-        array(s) (the HBM sink's result)."""
-        sink = DeviceSink(enabled=True, dtype=self.dtype)
-        task_id = None
+        array(s) (the HBM sink's result). A shard whose sink was refused
+        or lost raises: the task itself ends failed (conductor)."""
+        ptm = self.daemon.ptm
+        task_id = ptm._task_id(url, self.url_meta or UrlMeta())
         try:
-            async for resp in self.daemon.ptm.start_file_task(
+            store = ptm.storage_mgr.find_completed_task(task_id)
+            if store is not None:
+                return await self._ingest_from_storage(store)
+            async for _ in ptm.start_file_task(
                     DownloadRequest(url=url, url_meta=self.url_meta,
-                                    device_sink=sink,
+                                    device_sink=DeviceSink(
+                                        enabled=True, dtype=self.dtype),
                                     timeout_s=self.SHARD_TIMEOUT_S)):
-                task_id = resp.task_id or task_id
-            conductor = self.daemon.ptm.conductor(task_id) if task_id \
-                else None
-            ingest = conductor.device_ingest if conductor is not None \
-                else None
-            if ingest is not None:
-                arrays = await asyncio.to_thread(
-                    ingest.result, self.SHARD_TIMEOUT_S)
-                # the sink is consumed (arrays may be donated into the
-                # train step): a later epoch's reuse must rebuild from
-                # storage, never re-read this one
-                conductor.device_ingest = None
-            else:
-                arrays = await self._ingest_from_storage(task_id) \
-                    if task_id else None
-                if arrays is None:
-                    raise RuntimeError(
-                        f"shard {url}: no device ingest (wedged runtime, "
-                        "or content length unknown)")
+                pass
+            conductor = ptm.conductor(task_id)
+            arrays = await asyncio.to_thread(
+                conductor.device_ingest.result, self.SHARD_TIMEOUT_S)
+            # the sink is consumed (arrays may be donated into the train
+            # step): a later epoch's reuse must rebuild from storage,
+            # never re-read this one
+            conductor.device_ingest = None
             return arrays
         finally:
             # streamed-through on EVERY path: a failed shard's partial
             # pieces must not accumulate either
-            if self.delete_after and task_id is not None:
-                await self.daemon.ptm.delete_task(task_id)
+            if self.delete_after:
+                await ptm.delete_task(task_id)
 
     async def astream(self):
         """Async iterator over device arrays, ``depth`` shards in flight,

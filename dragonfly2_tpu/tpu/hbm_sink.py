@@ -154,7 +154,8 @@ class DeviceIngest:
                 raise ValueError("shard_specs incompatible with sharding")
             devices = list(sharding.mesh.devices.flat)
         elif devices is None:
-            devices = jax.devices()
+            # this host's chips: the sink is single-host by design
+            devices = jax.local_devices()
         self.devices = list(devices)
         self.shards_per_device = max(1, shards_per_device)
         self.on_shard_ready = on_shard_ready
@@ -214,6 +215,10 @@ class DeviceIngest:
         self._worker.start()
         if content_length < self.padded_length:  # pad tail trivially "present"
             self._coverage.add(content_length, self.padded_length)
+        log.info("device sink open: %d bytes -> %d shards on %d %s device(s) "
+                 "(%s)", content_length, n, len(self.devices),
+                 getattr(self.devices[0], "platform", "?"),
+                 getattr(self.devices[0], "device_kind", "?"))
 
     # ------------------------------------------------------------------
     # producer side (piece-landing path) — never blocks on DMA
@@ -231,8 +236,8 @@ class DeviceIngest:
         the moment its landing call stack unwinds. Device transfers read
         ONLY ``self.host``, never the caller's buffer."""
         if faultgate.ARMED:
-            # a raising script here exercises the conductor's sink-failure
-            # path: ingest disabled, download continues to disk
+            # a raising script here exercises the conductor's sink-loss
+            # path: the bytes finish landing on disk, the task FAILS
             faultgate.fire_sync("hbm.ingest")
         end = offset + len(data)
         if end > self.content_length:

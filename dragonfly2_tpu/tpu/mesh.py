@@ -1,4 +1,4 @@
-"""Device-mesh helpers: named-axis meshes over local or pod devices.
+"""Device-mesh helpers: named-axis meshes over this host's devices.
 
 The fabric uses meshes in two places: the HBM sink shards downloaded content
 across a mesh axis, and the trainer pjit-shards its training step. Axis
@@ -21,7 +21,7 @@ def make_mesh(axis_sizes: dict[str, int] | None = None, *, devices=None):
     from jax.sharding import Mesh
 
     if devices is None:
-        devices = jax.devices()
+        devices = jax.local_devices()
     n = len(devices)
     if not axis_sizes:
         axis_sizes = {"data": n}

@@ -164,8 +164,8 @@ def make_train_step(loss_fn, optimizer):
 
 def make_mesh(n_devices: int | None = None, *,
               dp: int | None = None) -> Mesh:
-    """A (dp, tp) mesh over available devices; tp gets the residue."""
-    devices = np.array(jax.devices())
+    """A (dp, tp) mesh over this host's devices; tp gets the residue."""
+    devices = np.array(jax.local_devices())
     n = n_devices or devices.size
     devices = devices[:n]
     if dp is None:

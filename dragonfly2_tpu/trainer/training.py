@@ -31,10 +31,28 @@ GNN_MODEL_NAME = features.GNN_MODEL_NAME
 # ---------------------------------------------------------------- fitting
 
 def _make_step(loss_fn, opt, mesh):
-    if mesh is not None and mesh.devices.size > 1:
+    if mesh is not None:
         return models.sharded_train_step(loss_fn, opt, mesh)
     import jax
     return jax.jit(models.make_train_step(loss_fn, opt))
+
+
+def _mesh(use_mesh: bool):
+    """Bring JAX up for a fit; a dp x tp mesh when this host has more
+    than one device."""
+    from ..tpu import runtime
+    devices = runtime.bring_up()
+    return models.make_mesh() if use_mesh and len(devices) > 1 else None
+
+
+def _placement(params, mesh) -> dict:
+    """Where the fit ran — returned metrics only, never serialized (the
+    blob stays a function of rows and seed)."""
+    import jax
+    leaves = jax.tree_util.tree_leaves(params)
+    return {"param_platforms": sorted({d.platform for leaf in leaves
+                                       for d in leaf.devices()}),
+            "mesh": dict(mesh.shape) if mesh is not None else None}
 
 
 def train_mlp(rows: list[dict], *, epochs: int = 40, batch_size: int = 512,
@@ -53,16 +71,21 @@ def train_mlp(rows: list[dict], *, epochs: int = 40, batch_size: int = 512,
         return None
     n = data["x"].shape[0]
     rng = np.random.default_rng(seed)
+    mesh = _mesh(use_mesh)
     key = jax.random.PRNGKey(seed)
     params = models.init_mlp(key)
     opt = models.make_optimizer(lr)
-    mesh = models.make_mesh() if use_mesh and len(jax.devices()) > 1 else None
     if mesh is not None:
         params = models.shard_params(params, mesh)
     opt_state = opt.init(params)
     step = _make_step(models.mlp_loss, opt, mesh)
 
     bs = min(batch_size, n)
+    if mesh is not None:
+        # P("dp") needs the batch to tile over dp: an odd row count rounds
+        # UP, the wraparound below fills the extra rows
+        dp = mesh.shape["dp"]
+        bs = -(-bs // dp) * dp
     # static batch shape: pad the epoch to a multiple of bs via wraparound
     steps_per_epoch = max(1, n // bs)
     first_loss = last_loss = None
@@ -72,7 +95,7 @@ def train_mlp(rows: list[dict], *, epochs: int = 40, batch_size: int = 512,
         for s in range(steps_per_epoch):
             idx = order[(s * bs) % n:(s * bs) % n + bs]
             if idx.size < bs:
-                idx = np.concatenate([idx, order[:bs - idx.size]])
+                idx = np.concatenate([idx, np.resize(order, bs - idx.size)])
             batch = {"x": data["x"][idx], "y": data["y"][idx]}
             if mesh is not None:
                 batch = models.shard_batch(batch, mesh)
@@ -91,10 +114,11 @@ def train_mlp(rows: list[dict], *, epochs: int = 40, batch_size: int = 512,
         "feature_dim": features.FEATURE_DIM,
         "feature_names": list(features.PARENT_FEATURES),
         "schema_version": features.FEATURE_SCHEMA_VERSION,
-        "devices": len(jax.devices()),
+        "devices": len(jax.local_devices()),
     }
     host_params = jax.tree_util.tree_map(np.asarray, params)
     data_bytes = serialize_params(host_params, metrics)
+    metrics.update(_placement(params, mesh))
     # version + wall clock ride in the RETURNED metrics only: the
     # serialized meta must be a function of (rows, seed) alone so the
     # same fit yields the same blob bytes — the rollout path dedupes on
@@ -118,16 +142,15 @@ def train_gnn(topo_rows: list[dict], *, epochs: int = 60, lr: float = 1e-3,
     if graph is None or float(graph["edge_mask"].sum()) < 4:
         return None
     batch = {k: v for k, v in graph.items() if k != "host_ids"}
+    mesh = _mesh(use_mesh)
     key = jax.random.PRNGKey(seed)
     params = models.init_gnn(key)
     opt = models.make_optimizer(lr)
-    mesh = models.make_mesh() if use_mesh and len(jax.devices()) > 1 else None
     if mesh is not None:
         params = models.shard_params(params, mesh)
         # graph batches replicate (node/edge dims aren't batch dims)
-        import jax as _jax
         from jax.sharding import NamedSharding, PartitionSpec as P
-        batch = {k: _jax.device_put(v, NamedSharding(mesh, P()))
+        batch = {k: jax.device_put(v, NamedSharding(mesh, P()))
                  for k, v in batch.items()}
     opt_state = opt.init(params)
     step = _make_step(models.gnn_loss, opt, mesh)
@@ -149,10 +172,11 @@ def train_gnn(topo_rows: list[dict], *, epochs: int = 60, lr: float = 1e-3,
         "seed": int(seed),
         "first_epoch_loss": first_loss,
         "final_loss": last_loss,
-        "devices": len(jax.devices()),
+        "devices": len(jax.local_devices()),
     }
     host_params = jax.tree_util.tree_map(np.asarray, params)
     data_bytes = serialize_params(host_params, metrics)
+    metrics.update(_placement(params, mesh))
     # same determinism contract as train_mlp: wall clock stays out of
     # the serialized meta so identical (rows, seed) → identical bytes
     metrics["version"] = version_of(data_bytes)

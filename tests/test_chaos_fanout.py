@@ -35,11 +35,7 @@ N_KILLED = 2
 SIZE = 96 << 20
 
 
-def test_chaos_wave_survives_leecher_and_seed_death(tmp_path, monkeypatch):
-    # daemons in this test never need jax; cut the per-boot topology probe
-    # from 15s to 2s so the seed RESTART lands inside the wave. Test-scoped
-    # (monkeypatch reverts): the subprocesses inherit it via os.environ.
-    monkeypatch.setenv("DF_TOPOLOGY_PROBE_TIMEOUT_S", "2")
+def test_chaos_wave_survives_leecher_and_seed_death(tmp_path):
     # ONE documented retry: the 1-vCPU host's 2-3x drift (see
     # bench calib) occasionally lands the kill windows badly — a chaos
     # scenario is rerun once from scratch before declaring failure; the

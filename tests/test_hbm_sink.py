@@ -338,99 +338,3 @@ class TestMesh:
         assert mesh.shape["data"] == n // 2
         with pytest.raises(ValueError):
             make_mesh({"data": 3}) if n % 3 else (_ for _ in ()).throw(ValueError())
-
-
-class TestJaxProbe:
-    def test_probe_ok_on_cpu_backend(self):
-        from dragonfly2_tpu.tpu.topology import probe_jax_devices
-        status, payload = probe_jax_devices(timeout_s=60)
-        assert status == "ok"
-        n_tpu, first, total = payload
-        assert total >= 1          # conftest pins the cpu backend
-        assert n_tpu == 0          # no tpu chips on the cpu backend
-
-    def test_wedged_runtime_disables_device_sink(self, monkeypatch, tmp_path):
-        """The wedged-runtime CONTRACT (VERDICT r04 weak #5): after a
-        timed-out probe the process must never touch jax again — the
-        daemon's device-sink factory refuses instead of hanging the event
-        loop behind the probe thread's jax init locks. The conductor
-        catches the refusal and continues to disk."""
-        from dragonfly2_tpu.common.errors import Code, DFError
-        from dragonfly2_tpu.daemon.config import DaemonConfig, StorageSection
-        from dragonfly2_tpu.daemon.daemon import Daemon
-        from dragonfly2_tpu.idl.messages import DeviceSink
-
-        monkeypatch.setattr(topology, "_local_probe_hung", True)
-        assert topology.runtime_wedged()
-        daemon = Daemon(DaemonConfig(workdir=str(tmp_path),
-                                     host_ip="127.0.0.1", hostname="w",
-                                     storage=StorageSection(
-                                         gc_interval_s=3600)))
-        factory = daemon.device_sink_builder(DeviceSink(enabled=True))
-        with pytest.raises(DFError) as exc:
-            factory(1 << 20)
-        assert exc.value.code == Code.UNAVAILABLE
-        # once the poison is gone, ensure_runtime_alive's bounded probe
-        # re-admits the (healthy cpu-backend) runtime: construction works
-        monkeypatch.setattr(topology, "_local_probe_hung", False)
-        ingest = factory(1 << 20)
-        assert ingest is not None
-        ingest.close()
-
-    def test_wedge_cache_prevents_repeat_probe_stalls(self, monkeypatch,
-                                                      tmp_path):
-        """A timed-out probe marks the host so sibling processes (a fleet
-        boot, a restart storm) skip their own full-timeout probe; a later
-        successful probe clears the marker."""
-        import builtins
-        import os
-        import time
-
-        # private marker path for this test (a bogus XLA_FLAGS key would
-        # abort jax's first backend init when run in isolation)
-        cache = str(tmp_path / "wedge-marker")
-        monkeypatch.setattr(topology, "_wedge_cache_path", lambda: cache)
-        monkeypatch.setattr(topology, "_local_probe_hung", False)
-
-        real_import = builtins.__import__
-
-        def hanging_import(name, *a, **kw):
-            if name == "jax":
-                time.sleep(20)
-            return real_import(name, *a, **kw)
-
-        monkeypatch.setattr(builtins, "__import__", hanging_import)
-        status, _ = topology.probe_jax_devices(timeout_s=0.3)
-        assert status == "timeout"
-        assert os.path.exists(cache), "timeout must write the wedge marker"
-        # marker fresh: the next probe answers instantly without touching
-        # jax at all (import hook restored -> a real probe would succeed)
-        monkeypatch.setattr(builtins, "__import__", real_import)
-        t0 = time.monotonic()
-        status, _ = topology.probe_jax_devices(timeout_s=30)
-        assert status == "timeout"
-        assert time.monotonic() - t0 < 1.0, "cached wedge must be instant"
-        assert topology.runtime_wedged()
-        os.unlink(cache)
-        status, _ = topology.probe_jax_devices(timeout_s=60)
-        assert status == "ok"
-        assert not os.path.exists(cache), "success must clear the marker"
-
-    def test_probe_reports_error_not_timeout_when_jax_breaks(self, monkeypatch):
-        """Absent/broken jax must surface as 'error' (with the exception),
-        not masquerade as a hung runtime."""
-        import builtins
-
-        from dragonfly2_tpu.tpu import topology
-
-        real_import = builtins.__import__
-
-        def broken_import(name, *a, **kw):
-            if name == "jax":
-                raise ImportError("jax exploded (test)")
-            return real_import(name, *a, **kw)
-
-        monkeypatch.setattr(builtins, "__import__", broken_import)
-        status, payload = topology.probe_jax_devices(timeout_s=10)
-        assert status == "error"
-        assert "exploded" in str(payload)

@@ -30,13 +30,25 @@ def free_port() -> int:
     return port
 
 
+def child_env() -> dict:
+    """Nothing steers a launcher's JAX (the suite's own JAX_PLATFORMS is
+    dropped too): a chip belongs to one process, so a launcher that needed
+    steering would be one that touches JAX."""
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    return {**env, "PYTHONPATH": REPO, "PYTHONUNBUFFERED": "1"}
+
+
+def off_jax(proc: subprocess.Popen) -> bool:
+    with open(f"/proc/{proc.pid}/maps") as f:
+        maps = f.read()
+    return "jaxlib" not in maps and "libtpu" not in maps
+
+
 def spawn(mod: str, *args: str) -> subprocess.Popen:
-    env = {**os.environ, "PYTHONPATH": REPO, "PYTHONUNBUFFERED": "1",
-           "JAX_PLATFORMS": "cpu"}
     return subprocess.Popen(
         [PY, "-m", f"dragonfly2_tpu.tools.{mod}", *args],
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True, env=env,
-        cwd=REPO)
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+        env=child_env(), cwd=REPO)
 
 
 def wait_line(proc: subprocess.Popen, needle: str, timeout: float = 150.0) -> str:
@@ -163,10 +175,15 @@ def test_full_stack_from_clis(tmp_path):
         rc = subprocess.run(
             [PY, "-m", "dragonfly2_tpu.tools.dfget", url, "-O", str(out),
              "--daemon-sock", sock, "--quiet"],
-            env={**os.environ, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu"},
-            cwd=REPO, capture_output=True, text=True, timeout=120)
+            env=child_env(), cwd=REPO, capture_output=True, text=True,
+            timeout=120)
         assert rc.returncode == 0, rc.stderr[-2000:]
         assert out.read_bytes() == blob
+        # the swarm moved a file and none of it took the chip (the trainer
+        # is the one launcher whose job is JAX)
+        for name, p in (("manager", mgr), ("seed", seed),
+                        ("scheduler", sched), ("leecher", leech)):
+            assert off_jax(p), f"{name} launcher has jax mapped"
     finally:
         for p in procs:
             if p.poll() is None:

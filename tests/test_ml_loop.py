@@ -102,6 +102,21 @@ class TestTraining:
     def test_too_few_rows_returns_none(self):
         assert training.train_mlp([], use_mesh=False) is None
 
+    def test_odd_row_count_fits_over_the_mesh(self):
+        """257 rows cannot tile dp=4 (conftest's 8 devices: dp 4 x tp 2):
+        the batch rounds up to a multiple of dp and wraps around."""
+        rng = np.random.default_rng(5)
+        rows = [{"features": rng.uniform(size=features.FEATURE_DIM).tolist(),
+                 "label": float(rng.uniform())} for _ in range(257)]
+        blob, metrics = training.train_mlp(rows, epochs=3)
+        assert metrics["mesh"] == {"dp": 4, "tp": 2}
+        assert metrics["param_platforms"] == ["cpu"]
+        assert np.isfinite(metrics["final_loss"])
+        # where the fit ran is not part of the model: same rows and seed,
+        # same blob, meshed or not (the meta's device count aside)
+        _, meta = serving.params_io.deserialize_params(blob)
+        assert "mesh" not in meta and "param_platforms" not in meta
+
 
 # ---------------------------------------------------------------- e2e loop
 

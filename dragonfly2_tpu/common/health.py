@@ -13,7 +13,11 @@ first-class, self-reporting event:
   back). Exported as the ``df_loop_lag_seconds`` histogram plus a
   high-water gauge; an overshoot past ``stall_threshold_s`` is a *stall*:
   the full await-chain stack dump plus active flight-recorder state goes
-  to the log and the ``/debug/health`` ring.
+  to the log and the ``/debug/health`` ring. The same tick reads the loop
+  thread's own CPU seconds (``getrusage(RUSAGE_THREAD)``: the monitor runs
+  on that thread) into ``PLANE.loop_samples`` and
+  ``df_loop_cpu_seconds_total{mode}``: lag says the loop was late, these
+  say whether it was working (user), in the kernel (sys) or waiting.
 
 * **Coroutine watchdog** — hot paths register *sections* (``with
   PLANE.watchdog.section("piece.wire", deadline_s=...)``) around awaits
@@ -42,6 +46,7 @@ from __future__ import annotations
 import asyncio
 import itertools
 import logging
+import resource
 import time
 import weakref
 from collections import deque
@@ -63,6 +68,9 @@ _loop_lag_max = REGISTRY.gauge(
     "df_loop_lag_max_seconds", "high-water event-loop lag since boot")
 _loop_stalls = REGISTRY.counter(
     "df_loop_stalls_total", "loop-lag samples past the stall threshold")
+_loop_cpu = REGISTRY.counter(
+    "df_loop_cpu_seconds_total", "CPU seconds of the thread that runs the "
+    "event loop, sampled by the health monitor on that thread", ("mode",))
 _overruns = REGISTRY.counter(
     "df_watchdog_overrun_total", "watchdog sections past their deadline",
     ("section",))
@@ -361,12 +369,17 @@ class HealthPlane:
     """
 
     MAX_EVENTS = 32
+    MAX_LOOP_SAMPLES = 4096          # 0.1 s ticks: the last 7 minutes
 
     def __init__(self) -> None:
         self.cfg = HealthConfig()
         self.slo = SLOEngine(self.cfg.budgets_ms())
         self.watchdog = Watchdog(self)
         self.events: deque = deque(maxlen=self.MAX_EVENTS)
+        # (time.monotonic(), lag_s, user CPU s, system CPU s) of the loop's
+        # own thread, one a tick: the loop's CPU seconds from the inside
+        # (is it the wall? user or kernel?), beside the lag they cause
+        self.loop_samples: deque = deque(maxlen=self.MAX_LOOP_SAMPLES)
         self.started_at = time.time()
         self.last_lag_s = 0.0
         self.max_lag_s = 0.0
@@ -406,6 +419,7 @@ class HealthPlane:
             if self._monitor is not None:
                 self._monitor.cancel()
                 self._monitor = None
+            self.loop_samples.clear()   # a disabled plane shows no series
             return
         if self._monitor is not None and self._monitor.done():
             self._monitor = None        # prior loop gone (sequential runs)
@@ -430,6 +444,13 @@ class HealthPlane:
 
     async def _run(self) -> None:
         loop = asyncio.get_running_loop()
+        # this coroutine runs on the loop's thread, so RUSAGE_THREAD is
+        # that thread's; a monitor on a fresh loop starts the series anew
+        # (another thread's seconds would break its monotony)
+        self.loop_samples.clear()
+        cpu = resource.getrusage(resource.RUSAGE_THREAD)
+        user0, sys0 = cpu.ru_utime, cpu.ru_stime
+        cpu_user, cpu_sys = _loop_cpu.labels("user"), _loop_cpu.labels("sys")
         while True:
             # re-read each tick: a later acquire() may retune the cadence
             interval = max(self.cfg.sample_interval_s, 0.01)
@@ -439,6 +460,12 @@ class HealthPlane:
             self.samples += 1
             self.last_lag_s = lag
             _loop_lag.observe(lag)
+            cpu = resource.getrusage(resource.RUSAGE_THREAD)
+            self.loop_samples.append(
+                (time.monotonic(), lag, cpu.ru_utime, cpu.ru_stime))
+            cpu_user.inc(cpu.ru_utime - user0)
+            cpu_sys.inc(cpu.ru_stime - sys0)
+            user0, sys0 = cpu.ru_utime, cpu.ru_stime
             if lag > self.max_lag_s:
                 self.max_lag_s = lag
                 _loop_lag_max.set(lag)

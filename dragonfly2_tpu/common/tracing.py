@@ -35,6 +35,7 @@ import logging
 import os
 import queue
 import secrets
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -295,6 +296,24 @@ def span(name: str, *, parent: SpanContext | None = None, **attrs):
 
 def current() -> SpanContext | None:
     return _current.get()
+
+
+_NO_SECTION = contextlib.nullcontext()
+
+
+def annotate(name: str):
+    """A SYNCHRONOUS section of the data path as a host span ``df:<name>``
+    in the JAX profiler's own trace, on the device trace's clock: what the
+    host was doing while the device idled, for whoever opens the trace.
+
+    Only in a process that already imported ``jax`` (the chip holder);
+    this never imports it, so launchers, schedulers and plain daemons stay
+    off JAX and off the chip. Free while no trace is being taken. Never
+    around an ``await``: a TraceMe is a per-thread stack, and the loop
+    interleaves tasks."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    section = getattr(profiler, "TraceAnnotation", None)
+    return _NO_SECTION if section is None else section("df:" + name)
 
 
 def traceparent() -> str:

@@ -19,6 +19,7 @@ import time
 from typing import Any, AsyncIterator
 
 from ..common import digest as digestlib
+from ..common import tracing
 from ..common.errors import Code, DFError
 from ..common.logging import with_fields
 from ..common.metrics import REGISTRY
@@ -61,6 +62,17 @@ _shard_bytes = REGISTRY.counter(
 
 # bound on waiting out a finished download's last device transfers
 SINK_DRAIN_TIMEOUT_S = 600.0
+
+
+def _stamped(fn, *args, **kwargs):
+    """A landing call as the storage thread runs it: its result with the
+    ``time.monotonic()`` stamps of its start and end, taken on that thread
+    (``run_io`` itself knows nothing of them), under a ``df:land`` span in
+    the profiler's trace."""
+    t0 = time.monotonic()
+    with tracing.annotate("land"):
+        out = fn(*args, **kwargs)
+    return out, t0, time.monotonic()
 
 
 class PeerTaskConductor:
@@ -314,11 +326,18 @@ class PeerTaskConductor:
         if (self.device_sink_factory is None or content_length <= 0
                 or self.device_ingest is not None or self.sink_error):
             return
+        t0 = time.monotonic()
         try:
-            self.device_ingest = self._make_device_ingest(content_length)
+            with tracing.annotate("sink_open"):
+                self.device_ingest = self._make_device_ingest(content_length)
         except Exception as exc:  # noqa: BLE001 - reported at finish
             self._sink_lost(f"device sink refused: {type(exc).__name__}: "
                             f"{exc}")
+            return
+        if self.flight is not None:
+            # the content-sized host buffer is allocated here, on the loop
+            self.flight.event(fr.SINK_OPEN, nbytes=content_length,
+                              dur_ms=(time.monotonic() - t0) * 1000.0)
 
     def _ingest_to_device(self, num: int, offset: int, data) -> bool:
         """Stage one piece into the device sink; False once the sink is
@@ -327,15 +346,44 @@ class PeerTaskConductor:
         here."""
         if self.device_ingest is None:
             return False
+        t0 = time.monotonic()
         try:
-            self.device_ingest.write(offset, data)
+            with tracing.annotate("stage_copy"):
+                self.device_ingest.write(offset, data)
         except Exception as exc:  # noqa: BLE001 - reported at finish
             self._sink_lost(f"device ingest write failed at piece {num}: "
                             f"{type(exc).__name__}: {exc}")
             return False
         if self.flight is not None:
-            self.flight.event(fr.HBM_DONE, num, nbytes=len(data))
+            # dur_ms: the staging copy itself (with the sink's coverage
+            # and spec bookkeeping), which rides the loop by design
+            t1 = time.monotonic()
+            self.flight.event(fr.HBM_DONE, num, nbytes=len(data),
+                              dur_ms=(t1 - t0) * 1000.0,
+                              t_ms=self.flight.ms_at(t1))
         return True
+
+    async def _land(self, num: int, nbytes: int, path: str | None,
+                    fn, *args, **kwargs):
+        """One landing on the storage executor, journaled: ``landed`` is
+        the thread's own run (write + verify), ``land_wait`` what the
+        landing waited beside it, for a storage thread and then for the
+        loop to resume this coroutine. ``path`` None: ``fn`` is
+        ``write_span`` and names the path it took in its result."""
+        t_submit = time.monotonic()
+        out, t_begin, t_end = await run_io(_stamped, fn, *args, **kwargs)
+        flight = self.flight
+        if flight is not None:
+            t_back = time.monotonic()
+            if path is None:
+                path = out[2]
+            flight.event(fr.LANDED, num, path, nbytes,
+                         dur_ms=(t_end - t_begin) * 1000.0,
+                         t_ms=flight.ms_at(t_begin))
+            flight.event(fr.LAND_WAIT, num, path, dur_ms=(
+                (t_begin - t_submit) + (t_back - t_end)) * 1000.0,
+                t_ms=flight.ms_at(t_back))
+        return out
 
     # ------------------------------------------------------------------
     # content-addressed dedupe (storage/castore.py)
@@ -789,7 +837,9 @@ class PeerTaskConductor:
             if write_span is not None:
                 spec = [(p.piece_num, p.range_start, p.range_size, p.digest)
                         for p in claim]
-                metas, corrupt, path = await run_io(
+                metas, corrupt, path = await self._land(
+                    claim[0].piece_num,
+                    sum(p.range_size for p in claim), None,
                     write_span, spec, data, base=base,
                     cost_ms=cost_ms_per_piece, source=parent_id)
                 _span_lands.labels(path).inc()
@@ -804,7 +854,8 @@ class PeerTaskConductor:
                     for p in claim:
                         lo = p.range_start - base
                         try:
-                            await run_io(
+                            await self._land(
+                                p.piece_num, p.range_size, "per_piece",
                                 self.storage.write_piece, p.piece_num,
                                 p.range_start, mv[lo:lo + p.range_size],
                                 p.digest, cost_ms=cost_ms_per_piece,
@@ -909,9 +960,10 @@ class PeerTaskConductor:
             # hashing+write can take ms at 16MiB — runs on the DEDICATED
             # storage executor (io_executor.py), not the shared default
             # pool, so piece landing never queues behind TLS handshakes
-            await run_io(self.storage.write_piece, num, offset,
-                         data, piece_digest, cost_ms=cost_ms,
-                         source=source, pre_verified=pre_verified)
+            await self._land(num, len(data), "per_piece",
+                             self.storage.write_piece, num, offset,
+                             data, piece_digest, cost_ms=cost_ms,
+                             source=source, pre_verified=pre_verified)
         finally:
             self._landing.discard(num)
         if num in self.ready:     # lost a race decided elsewhere

@@ -9,7 +9,15 @@ device transfer. The recorder captures every piece's lifecycle
 
 with parent peer id, source (p2p vs back-to-source), and byte counts, and
 can summarize a finished task (slowest-piece attribution, per-parent
-throughput, tail-latency breakdown, back-to-source ratio).
+throughput, tail-latency breakdown, back-to-source ratio). Beside the
+lifecycle it carries the *sections* of the data path, each with the
+seconds it ran (``dur_ms``): the loop's own copy off the wire
+(``wire_copy``), the storage thread's landing pass (``landed``) and how
+long it waited for a thread and for the loop (``land_wait``), the staging
+copy (``hbm_done``'s duration), the sink's allocation (``sink_open``) and
+what the piece workers did with their time (``worker_wait``,
+``worker_busy``) — so the daemon loop's seconds are split by the program
+and not guessed from outside.
 
 Overhead contract (bench-critical — every piece of a v5p fan-out crosses
 this path):
@@ -51,7 +59,29 @@ SCHEDULED = "scheduled"      # dispatcher handed the piece to a worker
 DISPATCHED = "dispatched"    # HTTP GET to the parent is about to fire
 FIRST_BYTE = "first_byte"    # first body chunk arrived (per request)
 WIRE_DONE = "wire_done"      # piece bytes fully on the wire, verified
-HBM_DONE = "hbm_done"        # piece staged for the device sink
+HBM_DONE = "hbm_done"        # piece staged for the device sink (dur_ms =
+# the staging copy itself, DeviceIngest.write, on the loop by design)
+# data-path sections: each carries the seconds it ran in dur_ms, so a
+# summary can say where wire_done -> hbm_done went and the benchmark can
+# add the loop thread's own sections up against its CPU seconds
+WIRE_COPY = "wire_copy"      # one per dispatch: the seconds _read_body ran
+# ON the loop (per-chunk copy + watermark store, awaits excluded),
+# bytes = bytes read; the chunk count adds to TaskFlight.wire_chunks
+LANDED = "landed"            # one per landing: the storage thread's write +
+# verify pass (t_ms = when the thread began, parent = the landing path:
+# native / python / per_piece, piece = the span's first piece)
+LAND_WAIT = "land_wait"      # the same landing's waiting: submitted ->
+# thread began, plus thread finished -> coroutine resumed on the loop
+SINK_OPEN = "sink_open"      # one per task: building the DeviceIngest (the
+# content-sized host buffer), on the loop; bytes = content length
+WORKER_WAIT = "worker_wait"  # at teardown, one per non-zero bucket of
+# PieceDispatcher.wait_stats (parent = no_piece_s / busy_s / seed_busy_s
+# / other_s): seconds the piece workers were parked with nothing to fetch
+WORKER_BUSY = "worker_busy"  # at teardown: seconds summed over all piece
+# workers inside _download_one (wire + landing + reports)
+# section kinds summarize() totals and keeps out of the piece rows
+SECTIONS = (WIRE_COPY, LANDED, LAND_WAIT, SINK_OPEN, WORKER_WAIT,
+            WORKER_BUSY)
 CORRUPT = "corrupt"          # digest mismatch at landing (parent = sender):
 # the piece was requeued; repeated corrupt events from one parent are the
 # dfdiag fingerprint of a corrupting peer (bad NIC/disk), and the summary
@@ -124,7 +154,7 @@ class TaskFlight:
     __slots__ = ("task_id", "peer_id", "started_at", "_m0", "events",
                  "serves", "state", "url", "report_drops", "_sum_key",
                  "_sum_cache", "qos_class", "tenant", "shards_total",
-                 "on_rung", "fail_reason")
+                 "on_rung", "fail_reason", "wire_chunks")
 
     def __init__(self, task_id: str, peer_id: str, *, url: str = "",
                  max_events: int = 4096, max_serves: int = 1024,
@@ -156,6 +186,9 @@ class TaskFlight:
         # (0 = not sharded) — set by the conductor so the summary's
         # shards block can report ready/total without replaying events
         self.shards_total = 0
+        # body chunks the wire handed the loop (one wake-up and one slice
+        # copy each): bytes_p2p over this is the chunk size a GiB rides in
+        self.wire_chunks = 0
         self._sum_key: tuple | None = None   # summarize() memo (see there)
         self._sum_cache: dict = {}
         # daemon-wide rung tally hook (FlightRecorder._note_rung): the
@@ -167,6 +200,10 @@ class TaskFlight:
 
     def now_ms(self) -> float:
         return (time.monotonic() - self._m0) * 1000.0
+
+    def ms_at(self, t: float) -> float:
+        """A ``time.monotonic()`` stamp on this flight's clock."""
+        return (t - self._m0) * 1000.0
 
     def event(self, stage: str, piece: int = -1, parent: str = ORIGIN,
               nbytes: int = 0, dur_ms: float = 0.0,
@@ -214,7 +251,7 @@ class TaskFlight:
         """Adopt a DeviceIngest's completed transfer spans ((monotonic
         start, end) pairs) as shard-level events on this flight's clock."""
         for idx, (t0, t1) in enumerate(spans):
-            self.events.append(((t0 - self._m0) * 1000.0, HBM_SHARD, idx,
+            self.events.append((self.ms_at(t0), HBM_SHARD, idx,
                                 ORIGIN, 0, (t1 - t0) * 1000.0))
 
     # -- consumption ---------------------------------------------------
@@ -244,6 +281,13 @@ class TaskFlight:
         per-parent throughput, slowest piece + its dominant stage, tail
         latencies, back-to-source ratio.
 
+        A piece row's ``hbm_ms`` is everything between the last byte off
+        the wire and the piece being staged for the sink: landing +
+        staging. ``stage_ms`` is the staging copy itself (``hbm_done``'s
+        duration) and ``land_ms`` the rest (the storage thread's write +
+        verify pass and its waits), so the two partition ``hbm_ms``.
+        ``sections_ms`` totals the data-path sections (SECTIONS) by kind.
+
         Memoized on (event count, state): a finished task is summarized
         at least twice back-to-back (SLO accounting at conductor finish,
         then the compact PeerResult form), and the O(events) walk need
@@ -269,9 +313,13 @@ class TaskFlight:
         bytes_placed = 0
         shard_rows: list[dict] = []
         shard_fallbacks = 0
+        sections = dict.fromkeys(SECTIONS, 0.0)
         for t, stage, piece, parent, nbytes, dur in self.events:
             if stage == HBM_SHARD:
                 hbm_dma_ms += dur
+                continue
+            if stage in sections:
+                sections[stage] += dur
                 continue
             if stage == SHARD_READY:
                 src = (SHARD_SRC_NAMES[piece]
@@ -315,6 +363,7 @@ class TaskFlight:
                 p["wire_dur"] = dur
             elif stage == HBM_DONE:
                 p[HBM_DONE] = t
+                p["stage_dur"] = dur
             else:
                 # pre-wire stages keyed by parent: endgame racers journal
                 # their own attempts, and only the entries of the parent
@@ -349,13 +398,16 @@ class TaskFlight:
                 "hbm_ms": max(hbm - wire_end, 0.0),
             }
             total = wire_end - sched + stages["hbm_ms"]
+            stage_ms = min(p.get("stage_dur", 0.0), stages["hbm_ms"])
             parent = winner
             row = {"piece": num, "parent": parent,
                    "source": "origin" if parent == ORIGIN else "p2p",
                    "bytes": p.get("bytes", 0),
                    "start_ms": round(sched, 3),
                    "total_ms": round(total, 3),
-                   **{k: round(v, 3) for k, v in stages.items()}}
+                   **{k: round(v, 3) for k, v in stages.items()},
+                   "land_ms": round(stages["hbm_ms"] - stage_ms, 3),
+                   "stage_ms": round(stage_ms, 3)}
             piece_rows.append(row)
             # accrued from the DEDUPED piece table, not per event (endgame
             # duplicates must not inflate a parent), and from wire time
@@ -415,6 +467,13 @@ class TaskFlight:
                         "p90": _pctl(totals, 0.90),
                         "p99": _pctl(totals, 0.99)},
             "hbm_dma_ms": round(hbm_dma_ms, 3),
+            # data-path sections by kind (ms summed over the task), the
+            # staging copies among them, and the wire's chunk count
+            "sections_ms": {
+                **{k: round(v, 3) for k, v in sections.items()},
+                "stage_copy": round(sum(r["stage_ms"]
+                                        for r in piece_rows), 3)},
+            "wire_chunks": self.wire_chunks,
             # the degradation-ladder trail and the rung the task ended on —
             # dfdiag's verdict names it so "why did this go to origin"
             # never needs log spelunking

@@ -82,7 +82,8 @@ class PieceDownloader:
 
     @staticmethod
     async def _read_body(resp, size: int, what: str,
-                         on_first=None, relay_open=None) -> bytearray:
+                         on_first=None, relay_open=None,
+                         meta: dict | None = None) -> bytearray:
         """Stream the body into ONE pooled buffer. Replaces
         ``resp.read()``: no chunk-list join copy, and — unlike the PR 3/4
         shape — NO digest folding here: hashing a 4-16 MiB piece on the
@@ -97,7 +98,11 @@ class PieceDownloader:
         in-flight relay span once acquired; the per-chunk watermark
         advance is one attribute store, and a failed read retires the
         span HERE, before the buffer returns to the pool — a relay
-        reader must never copy from recycled memory."""
+        reader must never copy from recycled memory. ``meta`` (the dict
+        that rides ``download_span``) gets ``chunks``, the body chunks
+        read, and ``copy_s``, the seconds this function itself ran on the
+        loop between them (the slice copy and the watermark store, not
+        the awaits): the flight journal's ``wire_copy``."""
         if faultgate.ARMED:
             # inside the request's timeout window: a 'hang' script parks
             # here until the per-piece deadline cancels the read, exactly
@@ -109,8 +114,10 @@ class PieceDownloader:
         try:
             mv = memoryview(buf)
             try:
-                off = 0
+                off = chunks = 0
+                copy_s = 0.0
                 async for chunk in resp.content.iter_any():
+                    t_chunk = time.perf_counter()
                     if off == 0 and faultgate.ARMED:
                         chunk = faultgate.corrupt("piece.wire", chunk,
                                                   key=what)
@@ -127,6 +134,11 @@ class PieceDownloader:
                     off += n
                     if span is not None:
                         span.advance(off)
+                    chunks += 1
+                    copy_s += time.perf_counter() - t_chunk
+                if meta is not None:
+                    meta["chunks"] = chunks
+                    meta["copy_s"] = copy_s
                 if off != size:
                     raise _classified(Code.CLIENT_PIECE_DOWNLOAD_FAIL,
                                       f"{what}: short read {off}/{size}",
@@ -195,7 +207,8 @@ class PieceDownloader:
                         resp.headers.get("X-DF-Relay") == "1"
                 return await self._read_body(resp, size, what,
                                              on_first=on_first_byte,
-                                             relay_open=relay_open)
+                                             relay_open=relay_open,
+                                             meta=meta)
 
         try:
             # hard per-piece deadline OUTSIDE aiohttp: the session's total
@@ -281,7 +294,8 @@ class PieceDownloader:
                         resp.headers.get("X-DF-Relay") == "1"
                 return await self._read_body(resp, size, what,
                                              on_first=on_first_byte,
-                                             relay_open=relay_open)
+                                             relay_open=relay_open,
+                                             meta=meta)
 
         try:
             # same hard per-span deadline as download_piece (see there)

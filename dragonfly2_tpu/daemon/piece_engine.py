@@ -214,6 +214,7 @@ class PieceEngine:
         self._channels = channel_pool if channel_pool is not None else ChannelPool()
         self._own_channels = channel_pool is None
         self.dispatcher = PieceDispatcher()
+        self._busy_s = 0.0          # summed over workers, in _download_one
         self._synchronizers: dict[str, _Synchronizer] = {}
         self._current_parents: dict[str, PeerAddr] = {}  # latest assignment
         self._need_back_source = False
@@ -270,14 +271,45 @@ class PieceEngine:
             if (result.size_scope == SizeScope.SMALL
                     and result.single_piece is not None
                     and result.single_piece.piece_info is not None):
-                ok = await self._pull_single(conductor, session,
-                                             result.single_piece)
+                # a single-piece task has no workers: this call is its one
+                # download, and counts as their busy seconds
+                t_busy = time.monotonic()
+                try:
+                    ok = await self._pull_single(conductor, session,
+                                                 result.single_piece)
+                finally:
+                    self._busy_s += time.monotonic() - t_busy
                 if ok:
                     return True
                 # fall through to the normal path: scheduler may still help
             return await self._pull_normal(conductor, session)
         finally:
+            self._journal_workers(conductor.flight)
             await self._teardown()
+
+    def _journal_workers(self, flight) -> None:
+        """What the piece workers did with their time, into the flight
+        journal before its ``done``: the dispatcher's wait buckets (parked
+        with nothing to fetch, by why) against the seconds inside
+        ``_download_one``. Starved workers are the protocol's doing; busy
+        ones on a slow task are the loop's."""
+        if flight is None:
+            return
+        for bucket, secs in self.dispatcher.wait_stats.items():
+            if secs > 0:
+                flight.event(fr.WORKER_WAIT, parent=bucket,
+                             dur_ms=secs * 1000.0)
+        flight.event(fr.WORKER_BUSY, dur_ms=self._busy_s * 1000.0)
+
+    @staticmethod
+    def _journal_wire(flight, num: int, parent_id: str, nbytes: int,
+                      meta: dict) -> None:
+        """One dispatch's ``wire_copy``: what ``_read_body`` left in the
+        meta dict that rode the download."""
+        if flight is not None:
+            flight.event(fr.WIRE_COPY, num, parent_id, nbytes,
+                         dur_ms=meta.get("copy_s", 0.0) * 1000.0)
+            flight.wire_chunks += meta.get("chunks", 0)
 
     async def _pull_single(self, conductor, session, single) -> bool:
         info: PieceInfo = single.piece_info
@@ -320,6 +352,8 @@ class PieceEngine:
                 conductor, info, single.dst_peer_id, t0, ok=False,
                 code=exc.code, fail_code=fcode))
             return False
+        self._journal_wire(flight, info.piece_num, single.dst_peer_id,
+                           info.range_size, wire_meta)
         t_wire = flight.now_ms() if flight is not None else 0.0
         try:
             placed, corrupt, raced = await conductor.on_span_from_peer(
@@ -613,7 +647,11 @@ class PieceEngine:
                 # grow them on starvation pings — see rpcserver._SuperSeed)
                 await self._maybe_ping()
                 continue
-            await self._download_one(conductor, session, d)
+            t_busy = time.monotonic()
+            try:
+                await self._download_one(conductor, session, d)
+            finally:
+                self._busy_s += time.monotonic() - t_busy
 
     async def _maybe_ping(self) -> None:
         if not self.dispatcher.starving():
@@ -744,6 +782,8 @@ class PieceEngine:
                     code=exc.code, fail_code=fcode))
             return
         per_piece_cost = max(1, cost // len(d.pieces))
+        self._journal_wire(flight, d.piece.piece_num, d.parent.peer_id,
+                           d.size(), wire_meta)
         # timestamp before the landing await, journaled only for pieces
         # that actually land — an endgame duplicate must not overwrite the
         # real deliverer's attribution

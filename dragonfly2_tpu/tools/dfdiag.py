@@ -20,7 +20,9 @@ pod-level breach — so a chaos pipeline can gate on
 ``dfdiag --pod ... --json``.
 
 Waterfall legend: ``.`` queue (rate-limiter wait), ``-`` ttfb (request +
-parent-side queueing), ``=`` wire transfer, ``#`` HBM staging.
+parent-side queueing), ``=`` wire transfer, ``#`` landing + HBM staging
+(last byte off the wire to the piece staged for the sink: the storage
+thread's write + verify pass and its waits, then the staging copy).
 """
 
 from __future__ import annotations
@@ -41,7 +43,7 @@ STAGES = (
     ("queue_ms", ".", "local queueing"),
     ("ttfb_ms", "-", "parent queueing (time to first byte)"),
     ("wire_ms", "=", "wire transfer"),
-    ("hbm_ms", "#", "HBM staging"),
+    ("hbm_ms", "#", "landing + HBM staging"),
 )
 
 
@@ -123,6 +125,22 @@ def verdict(summary: dict) -> str:
     name = next(n for k, _, n in STAGES if k == key)
     parts = [f"verdict: {100 * stage_totals[key] / grand:.0f}% of piece "
              f"time went to {name}"]
+    staged = sum(r.get("stage_ms", 0.0) for r in rows)
+    if staged > 0 and stage_totals["hbm_ms"] > 0:
+        # hbm_ms is landing + staging: say which of the two it was
+        share = 100 * staged / stage_totals["hbm_ms"]
+        parts.append(
+            f"of landing + HBM staging, {100 - share:.0f}% was landing "
+            "(the storage thread's write + verify and its waits) and "
+            f"{share:.0f}% the staging copy on the daemon loop")
+    sec = summary.get("sections_ms") or {}
+    lived = sec.get("worker_wait", 0.0) + sec.get("worker_busy", 0.0)
+    if lived > 0:
+        parts.append(
+            f"piece workers were parked {100 * sec['worker_wait'] / lived:.0f}"
+            "% of their time with nothing to fetch"
+            + (" — the protocol starved them, not this host"
+               if sec["worker_wait"] > sec["worker_busy"] else ""))
     slow = summary.get("slowest_piece")
     if slow:
         who = slow.get("parent") or "origin"

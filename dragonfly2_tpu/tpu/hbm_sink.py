@@ -35,7 +35,7 @@ from typing import Any, Callable
 
 import numpy as np
 
-from ..common import faultgate
+from ..common import faultgate, tracing
 from ..common.metrics import REGISTRY
 
 log = logging.getLogger("df.storage.hbm")
@@ -54,8 +54,6 @@ _hbm_bytes = REGISTRY.counter(
     "df_hbm_staged_bytes_total", "bytes staged into the host buffer")
 _hbm_queue = REGISTRY.gauge(
     "df_hbm_transfer_queue_depth", "shard transfers enqueued, not yet done")
-_hbm_done = REGISTRY.gauge(
-    "df_hbm_done_fraction", "coverage fraction of the most recent sink")
 
 
 class CoverageMap:
@@ -245,7 +243,6 @@ class DeviceIngest:
         self.host[offset:end] = np.frombuffer(data, dtype=np.uint8)
         self._coverage.add(offset, end)
         _hbm_bytes.inc(len(data))
-        _hbm_done.set(self.done_fraction())
         if self._specs is not None:
             # manifest mode: enqueue every named range this span touches
             # (a piece straddling a shard boundary can complete two)
@@ -316,13 +313,15 @@ class DeviceIngest:
                     view = self.host[s:e].view(self.dtype)
                     device = self.devices[shard // self.shards_per_device]
                 t0 = time.monotonic()
-                arr = self._device_put(view, device)
-                # span must end at transfer COMPLETION, not dispatch — on
-                # backends where device_put returns before the DMA lands,
-                # a dispatch-end span would report overlap that never ran
-                wait = getattr(arr, "block_until_ready", None)
-                if wait is not None:
-                    wait()
+                with tracing.annotate("hbm_transfer"):
+                    arr = self._device_put(view, device)
+                    # span must end at transfer COMPLETION, not dispatch —
+                    # on backends where device_put returns before the DMA
+                    # lands, a dispatch-end span would report overlap that
+                    # never ran
+                    wait = getattr(arr, "block_until_ready", None)
+                    if wait is not None:
+                        wait()
                 t1 = time.monotonic()
                 with self._lock:
                     self._shard_arrays[shard] = arr

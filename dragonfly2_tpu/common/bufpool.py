@@ -14,9 +14,12 @@ Contract (the reuse-safety rules the pool's consumers live by):
   dirty — callers must overwrite every byte they later read (the
   downloader's short/long-read checks already guarantee a full fill).
 * ``release(buf)`` parks the buffer for reuse. The caller promises that no
-  consumer still references its memory: storage writes have returned and
-  the HBM sink's staging memcpy (``DeviceIngest.write``) has completed —
-  both are synchronous-before-release in the landing path by construction.
+  consumer still references its memory: the storage write has returned,
+  and with it the staging copy into a device sink, which the landing makes
+  on its storage thread in the same hop (``tpu.hbm_sink.StageLease``) —
+  when ``on_span_from_peer`` returns, nothing reads the buffer any more.
+  (The sink's own file-sized host buffers are recycled the same way, by
+  ``tpu.hbm_sink.SinkBufferPool``.)
 * A buffer released while a ``memoryview`` over it is still exported is
   NOT recycled: release probes with a resize (append+pop), which raises
   ``BufferError`` iff exports exist, and such buffers are discarded

@@ -14,7 +14,7 @@ stress tool can arm with deterministic scripts:
                     request's timeout window, so 'hang' trips the
                     per-piece deadline exactly like a wedged parent)
     source.fetch    source/client.py module-level download()
-    hbm.ingest      tpu/hbm_sink.py DeviceIngest.write (sync path)
+    hbm.ingest      tpu/hbm_sink.py DeviceIngest.commit (sync path)
     sched.register  daemon/scheduler_session.py register, keyed by the
                     scheduler address under attempt
     pex.gossip      daemon/pex.py gossip round, keyed by the target peer

@@ -335,41 +335,84 @@ class PeerTaskConductor:
                             f"{exc}")
             return
         if self.flight is not None:
-            # the content-sized host buffer is allocated here, on the loop
+            # the content-sized host buffer is leased here, on the loop;
+            # parent: whether the pool had a released one for it
+            hit = getattr(self.device_ingest, "pool_hit", None)
             self.flight.event(fr.SINK_OPEN, nbytes=content_length,
+                              parent=("" if hit is None
+                                      else "hit" if hit else "miss"),
                               dur_ms=(time.monotonic() - t0) * 1000.0)
 
-    def _ingest_to_device(self, num: int, offset: int, data) -> bool:
-        """Stage one piece into the device sink; False once the sink is
-        lost. The ONE copy of the write/journal/loss sequence — every
-        landing path (pieces, spans, adoption, placement) stages through
-        here."""
+    def _stage_lease(self):
+        """A landing's hold on the device sink's host buffer, handed to
+        the storage call as ``stage=`` so that the staging copy of each
+        verified piece runs on the storage thread, in the landing's own
+        hop. None for a task with no sink (such a landing carries no
+        extra argument). The caller releases it once the landing has
+        returned: until then the sink keeps the buffer, even if the sink
+        is lost or closed meanwhile."""
+        ingest = self.device_ingest
+        return ingest.lease() if ingest is not None else None
+
+    def _ingest_to_device(self, num: int, offset: int, nbytes: int,
+                          lease) -> bool:
+        """Account one piece that a landing staged into the device sink
+        through ``lease``; False once the sink is lost. The ONE copy of
+        the commit/journal/loss sequence — every landing path (pieces,
+        spans, adoption, placement) comes through here, on the loop, after
+        its landing has returned and so after the staging copy has."""
         if self.device_ingest is None:
             return False
         t0 = time.monotonic()
         try:
-            with tracing.annotate("stage_copy"):
-                self.device_ingest.write(offset, data)
+            if lease.error is not None:
+                raise lease.error
+            self.device_ingest.commit(offset, nbytes)
         except Exception as exc:  # noqa: BLE001 - reported at finish
             self._sink_lost(f"device ingest write failed at piece {num}: "
                             f"{type(exc).__name__}: {exc}")
             return False
         if self.flight is not None:
-            # dur_ms: the staging copy itself (with the sink's coverage
-            # and spec bookkeeping), which rides the loop by design
+            # dur_ms: the loop's seconds in here, which is the sink's
+            # bookkeeping (coverage map, spec scan, enqueue); the staging
+            # copy ran in the landing (flight ``staged``)
             t1 = time.monotonic()
-            self.flight.event(fr.HBM_DONE, num, nbytes=len(data),
+            self.flight.event(fr.HBM_DONE, num, nbytes=nbytes,
                               dur_ms=(t1 - t0) * 1000.0,
                               t_ms=self.flight.ms_at(t1))
         return True
 
+    async def _stage_from_disk(self, num: int, offset: int,
+                               size: int) -> bool:
+        """Stage a piece whose VERIFIED bytes are on disk and came off no
+        wire in this conductor (adoption, placement, a piece an earlier
+        conductor recorded): read and copied on the storage thread
+        (journaled as a landing of path ``disk``), then accounted. False
+        with no sink, or once it is lost."""
+        lease = self._stage_lease()
+        if lease is None:
+            return False
+
+        def read_and_stage(stage) -> None:
+            stage.copy(offset, self.storage.read_piece(num))
+
+        try:
+            await self._land(num, size, "disk", read_and_stage, stage=lease)
+            return self._ingest_to_device(num, offset, size, lease)
+        finally:
+            lease.release()
+
     async def _land(self, num: int, nbytes: int, path: str | None,
-                    fn, *args, **kwargs):
+                    fn, *args, stage=None, **kwargs):
         """One landing on the storage executor, journaled: ``landed`` is
-        the thread's own run (write + verify), ``land_wait`` what the
-        landing waited beside it, for a storage thread and then for the
-        loop to resume this coroutine. ``path`` None: ``fn`` is
-        ``write_span`` and names the path it took in its result."""
+        the thread's write + verify pass, ``staged`` the staging copies it
+        made beside that into the device sink (``stage``: the sink's
+        lease, handed on to ``fn`` where there is one), ``land_wait`` what
+        the landing waited, for a storage thread and then for the loop to
+        resume this coroutine. ``path`` None: ``fn`` is ``write_span`` and
+        names the path it took in its result."""
+        if stage is not None:
+            kwargs["stage"] = stage
         t_submit = time.monotonic()
         out, t_begin, t_end = await run_io(_stamped, fn, *args, **kwargs)
         flight = self.flight
@@ -377,9 +420,14 @@ class PeerTaskConductor:
             t_back = time.monotonic()
             if path is None:
                 path = out[2]
+            copy_s = stage.seconds if stage is not None else 0.0
             flight.event(fr.LANDED, num, path, nbytes,
-                         dur_ms=(t_end - t_begin) * 1000.0,
+                         dur_ms=(t_end - t_begin - copy_s) * 1000.0,
                          t_ms=flight.ms_at(t_begin))
+            if copy_s:
+                flight.event(fr.STAGED, num, path, stage.nbytes,
+                             dur_ms=copy_s * 1000.0,
+                             t_ms=flight.ms_at(t_end))
             flight.event(fr.LAND_WAIT, num, path, dur_ms=(
                 (t_begin - t_submit) + (t_back - t_end)) * 1000.0,
                 t_ms=flight.ms_at(t_back))
@@ -421,9 +469,7 @@ class PeerTaskConductor:
         self._open_device_sink(self.content_length)
         for num in sorted(ts.md.pieces):
             p = ts.md.pieces[num]
-            if self.device_ingest is not None:
-                self._ingest_to_device(
-                    num, p.start, await run_io(self.storage.read_piece, num))
+            await self._stage_from_disk(num, p.start, p.size)
             async with self._piece_cond:
                 self.ready.add(num)
                 self.completed_length += p.size
@@ -479,9 +525,7 @@ class PeerTaskConductor:
                 self._landing.discard(num)
             if not landed or num in self.ready:
                 continue
-            if self.device_ingest is not None:
-                self._ingest_to_device(
-                    num, offset, await run_io(self.storage.read_piece, num))
+            await self._stage_from_disk(num, offset, size)
             async with self._piece_cond:
                 if num in self.ready:
                     continue
@@ -816,8 +860,9 @@ class PeerTaskConductor:
         of the three lists: those verified at landing and are safely
         reportable as complete. The caller owns ``data`` and may release
         it to the buffer pool as soon as this returns: the storage write
-        and the HBM staging memcpy have both completed by then (the
-        pool's reuse-safety contract).
+        and, inside the same storage hop, the staging copy into the device
+        sink are complete when the landing returns, and nothing here reads
+        ``data`` after it (the pool's reuse-safety contract).
         """
         if self.storage is None:
             raise DFError(Code.CLIENT_STORAGE_ERROR,
@@ -830,8 +875,22 @@ class PeerTaskConductor:
                  and p.piece_num not in self._landing]
         if not claim:
             return [], [], raced
+        lease = self._stage_lease()
         for p in claim:             # same dedup-race claim as _land_piece
             self._landing.add(p.piece_num)
+        try:
+            return await self._land_span(parent_id, claim, data, base,
+                                         cost_ms_per_piece, raced, lease)
+        finally:
+            if lease is not None:
+                lease.release()
+
+    async def _land_span(self, parent_id: str, claim: list[PieceInfo], data,
+                         base: int, cost_ms_per_piece: int, raced: list[int],
+                         lease) -> tuple[list[int], list[int], list[int]]:
+        """``on_span_from_peer`` once the pieces are claimed; ``lease`` is
+        the device sink's (None with no sink), held by the caller until
+        this returns."""
         try:
             write_span = getattr(self.storage, "write_span", None)
             if write_span is not None:
@@ -841,7 +900,8 @@ class PeerTaskConductor:
                     claim[0].piece_num,
                     sum(p.range_size for p in claim), None,
                     write_span, spec, data, base=base,
-                    cost_ms=cost_ms_per_piece, source=parent_id)
+                    cost_ms=cost_ms_per_piece, source=parent_id,
+                    stage=lease)
                 _span_lands.labels(path).inc()
                 landed_nums = [m.num for m in metas]
             else:
@@ -853,19 +913,23 @@ class PeerTaskConductor:
                 try:
                     for p in claim:
                         lo = p.range_start - base
+                        staged = lease.nbytes if lease is not None else 0
                         try:
                             await self._land(
                                 p.piece_num, p.range_size, "per_piece",
                                 self.storage.write_piece, p.piece_num,
                                 p.range_start, mv[lo:lo + p.range_size],
                                 p.digest, cost_ms=cost_ms_per_piece,
-                                source=parent_id)
+                                source=parent_id, stage=lease)
                         except DFError as exc:
                             if exc.code == Code.CLIENT_DIGEST_MISMATCH:
                                 corrupt.append(p.piece_num)
                                 continue
                             raise
-                        landed_nums.append(p.piece_num)
+                        if lease is None or lease.took(staged):
+                            landed_nums.append(p.piece_num)
+                        # else write_piece found it recorded and skipped
+                        # it: one of the on_disk pieces below
                 finally:
                     mv.release()
         finally:
@@ -889,26 +953,22 @@ class PeerTaskConductor:
         placed += sorted(on_disk)
         if not placed:
             return [], corrupt, raced
-        if self.device_ingest is not None:
-            # staging memcpy per landed piece, inline (see _land_piece for
-            # why this never rides an executor); the view dies before the
-            # caller can recycle the buffer
-            view = memoryview(data)
-            try:
-                for n in placed:
-                    p = by_num[n]
-                    if n in on_disk:
-                        # this span's copy of an already-recorded piece
-                        # was never digest-checked — stage the VERIFIED
-                        # bytes from disk instead
-                        staged = await run_io(self.storage.read_piece, n)
-                    else:
-                        lo = p.range_start - base
-                        staged = view[lo:lo + p.range_size]
-                    if not self._ingest_to_device(n, p.range_start, staged):
-                        break
-            finally:
-                view.release()
+        if lease is not None:
+            # the landing staged every piece it recorded, on its storage
+            # thread; what is left for the loop is the sink's bookkeeping
+            for n in placed:
+                p = by_num[n]
+                if n in on_disk:
+                    # this span's copy of an already-recorded piece was
+                    # never digest-checked, and write_span skipped it:
+                    # stage the VERIFIED bytes from disk instead
+                    ok = await self._stage_from_disk(n, p.range_start,
+                                                     p.range_size)
+                else:
+                    ok = self._ingest_to_device(n, p.range_start,
+                                                p.range_size, lease)
+                if not ok:
+                    break
         events = []
         counted = []
         async with self._piece_cond:
@@ -956,24 +1016,35 @@ class PeerTaskConductor:
             # device-ingest writes, duplicate scheduler success reports)
             return False
         self._landing.add(num)
+        lease = self._stage_lease()
         try:
             # hashing+write can take ms at 16MiB — runs on the DEDICATED
             # storage executor (io_executor.py), not the shared default
-            # pool, so piece landing never queues behind TLS handshakes
+            # pool, so piece landing never queues behind TLS handshakes.
+            # The staging copy into the device sink rides the same hop,
+            # after the piece has verified (write_piece's ``stage``)
             await self._land(num, len(data), "per_piece",
                              self.storage.write_piece, num, offset,
                              data, piece_digest, cost_ms=cost_ms,
-                             source=source, pre_verified=pre_verified)
+                             source=source, pre_verified=pre_verified,
+                             stage=lease)
         finally:
             self._landing.discard(num)
+            if lease is not None:
+                lease.release()
         if num in self.ready:     # lost a race decided elsewhere
             return False
-        # write() is a ~1ms memcpy + transfer-queue enqueue — the DMA
-        # itself runs on the sink's own thread and is never awaited here.
-        # Called inline: routing it through to_thread would queue the
-        # memcpy behind multi-ms piece-hashing jobs in the shared executor
-        # and serialize ingest with storage writes.
-        self._ingest_to_device(num, offset, data)
+        if lease is not None:
+            # what is left for the loop is the sink's bookkeeping and the
+            # transfer-queue enqueue — the DMA itself runs on the sink's
+            # own thread and is never awaited here
+            if lease.took(0):
+                self._ingest_to_device(num, offset, len(data), lease)
+            else:
+                # write_piece found the piece recorded (an earlier
+                # conductor over this storage) and skipped it: ``data``
+                # was never digest-checked, the bytes on disk were
+                await self._stage_from_disk(num, offset, len(data))
         if self.shaper is not None:
             self.shaper.record(self.task_id, len(data))
         async with self._piece_cond:

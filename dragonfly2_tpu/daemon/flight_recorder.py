@@ -12,9 +12,11 @@ can summarize a finished task (slowest-piece attribution, per-parent
 throughput, tail-latency breakdown, back-to-source ratio). Beside the
 lifecycle it carries the *sections* of the data path, each with the
 seconds it ran (``dur_ms``): the loop's own copy off the wire
-(``wire_copy``), the storage thread's landing pass (``landed``) and how
-long it waited for a thread and for the loop (``land_wait``), the staging
-copy (``hbm_done``'s duration), the sink's allocation (``sink_open``) and
+(``wire_copy``), the storage thread's landing pass (``landed``), the
+staging copy it makes in the same hop (``staged``) and how long the
+landing waited for a thread and for the loop (``land_wait``), the sink's
+bookkeeping on the loop (``hbm_done``'s duration), the sink's opening
+(``sink_open``) and
 what the piece workers did with their time (``worker_wait``,
 ``worker_busy``) — so the daemon loop's seconds are split by the program
 and not guessed from outside.
@@ -59,8 +61,10 @@ SCHEDULED = "scheduled"      # dispatcher handed the piece to a worker
 DISPATCHED = "dispatched"    # HTTP GET to the parent is about to fire
 FIRST_BYTE = "first_byte"    # first body chunk arrived (per request)
 WIRE_DONE = "wire_done"      # piece bytes fully on the wire, verified
-HBM_DONE = "hbm_done"        # piece staged for the device sink (dur_ms =
-# the staging copy itself, DeviceIngest.write, on the loop by design)
+HBM_DONE = "hbm_done"        # piece staged for the device sink and
+# accounted there (dur_ms = the loop's seconds in that accounting,
+# DeviceIngest.commit: coverage map, spec scan, transfer enqueue; the
+# staging copy itself is ``staged``)
 # data-path sections: each carries the seconds it ran in dur_ms, so a
 # summary can say where wire_done -> hbm_done went and the benchmark can
 # add the loop thread's own sections up against its CPU seconds
@@ -70,17 +74,24 @@ WIRE_COPY = "wire_copy"      # one per dispatch: the seconds _read_body ran
 LANDED = "landed"            # one per landing: the storage thread's write +
 # verify pass (t_ms = when the thread began, parent = the landing path:
 # native / python / per_piece, piece = the span's first piece)
+STAGED = "staged"            # the same landing's staging copies: verified
+# pieces into the device sink's host buffer, on the storage thread and in
+# the landing's hop (dur_ms = the copies' seconds, which ``landed`` leaves
+# out; bytes = bytes copied; parent as ``landed``, or ``disk`` for bytes
+# read back from the store). Only for tasks with a device sink
 LAND_WAIT = "land_wait"      # the same landing's waiting: submitted ->
 # thread began, plus thread finished -> coroutine resumed on the loop
-SINK_OPEN = "sink_open"      # one per task: building the DeviceIngest (the
-# content-sized host buffer), on the loop; bytes = content length
+SINK_OPEN = "sink_open"      # one per task: building the DeviceIngest, on
+# the loop; bytes = content length, parent = how its content-sized host
+# buffer was leased from the sink buffer pool (``hit``: a released one,
+# its pages already there; ``miss``: a fresh one)
 WORKER_WAIT = "worker_wait"  # at teardown, one per non-zero bucket of
 # PieceDispatcher.wait_stats (parent = no_piece_s / busy_s / seed_busy_s
 # / other_s): seconds the piece workers were parked with nothing to fetch
 WORKER_BUSY = "worker_busy"  # at teardown: seconds summed over all piece
 # workers inside _download_one (wire + landing + reports)
 # section kinds summarize() totals and keeps out of the piece rows
-SECTIONS = (WIRE_COPY, LANDED, LAND_WAIT, SINK_OPEN, WORKER_WAIT,
+SECTIONS = (WIRE_COPY, LANDED, STAGED, LAND_WAIT, SINK_OPEN, WORKER_WAIT,
             WORKER_BUSY)
 CORRUPT = "corrupt"          # digest mismatch at landing (parent = sender):
 # the piece was requeued; repeated corrupt events from one parent are the
@@ -282,10 +293,11 @@ class TaskFlight:
         latencies, back-to-source ratio.
 
         A piece row's ``hbm_ms`` is everything between the last byte off
-        the wire and the piece being staged for the sink: landing +
-        staging. ``stage_ms`` is the staging copy itself (``hbm_done``'s
-        duration) and ``land_ms`` the rest (the storage thread's write +
-        verify pass and its waits), so the two partition ``hbm_ms``.
+        the wire and the piece being accounted in the sink: landing +
+        staging. ``stage_ms`` is the sink's accounting on the loop
+        (``hbm_done``'s duration) and ``land_ms`` the rest (the storage
+        thread's write + verify pass with the staging copy in it, and its
+        waits), so the two partition ``hbm_ms``.
         ``sections_ms`` totals the data-path sections (SECTIONS) by kind.
 
         Memoized on (event count, state): a finished task is summarized
@@ -468,7 +480,8 @@ class TaskFlight:
                         "p99": _pctl(totals, 0.99)},
             "hbm_dma_ms": round(hbm_dma_ms, 3),
             # data-path sections by kind (ms summed over the task), the
-            # staging copies among them, and the wire's chunk count
+            # summed ``stage_ms`` among them (``stage_copy``: the name from
+            # when the copy ran there), and the wire's chunk count
             "sections_ms": {
                 **{k: round(v, 3) for k, v in sections.items()},
                 "stage_copy": round(sum(r["stage_ms"]

@@ -790,13 +790,14 @@ class PieceEngine:
         t_wire = flight.now_ms() if flight is not None else 0.0
         try:
             # ONE landing hop for the whole span (storage write + verify
-            # fused off-loop; HBM memcpy inline) — pre-PR5 this was one
+            # and the HBM staging copy, fused off-loop) — pre-PR5 this was one
             # to_thread + one hash pass + one write PER piece
             placed, corrupt, raced = await conductor.on_span_from_peer(
                 d.parent.peer_id, d.pieces, buf, per_piece_cost)
         finally:
-            # landing (including the sink's staging memcpy) has completed:
-            # the buffer is recyclable — this kills the 4-16 MiB
+            # landing (with the sink's staging copy, made inside it on the
+            # storage thread) has completed: the buffer is recyclable —
+            # this kills the 4-16 MiB
             # alloc/free churn per download at fan-out. The relay span is
             # retired FIRST: its bytes now serve from storage (or, if a
             # piece failed verification, stop being servable at all)

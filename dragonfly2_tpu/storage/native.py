@@ -90,6 +90,22 @@ def _bind(lib) -> None:
         lib._df_has_span_io = True
     except AttributeError:
         lib._df_has_span_io = False
+    try:
+        # int df_span_write_staged(fd, offset, data, uint64* piece_sizes,
+        #                          n_pieces, uint32* crcs_out,
+        #                          int64* expect, uint8* stage_dst,
+        #                          uint64* stage_ns_out) — df_span_write
+        # that also copies each verified piece into a device sink's host
+        # buffer; bound separately for the same reason
+        lib.df_span_write_staged.argtypes = [
+            ctypes.c_int, ctypes.c_uint64, ctypes.c_char_p,
+            ctypes.POINTER(ctypes.c_uint64), ctypes.c_size_t,
+            ctypes.POINTER(ctypes.c_uint32), ctypes.POINTER(ctypes.c_int64),
+            ctypes.c_void_p, ctypes.POINTER(ctypes.c_uint64)]
+        lib.df_span_write_staged.restype = ctypes.c_int
+        lib._df_has_span_stage = True
+    except AttributeError:
+        lib._df_has_span_stage = False
 
 
 def available() -> bool:
@@ -173,6 +189,38 @@ def span_write(fd: int, offset: int, data: bytes | bytearray | memoryview,
     if rc < 0:
         raise OSError(-rc, os.strerror(-rc))
     return [f"{c:08x}" for c in crcs]
+
+
+def span_write_staged(fd: int, offset: int,
+                      data: bytes | bytearray | memoryview,
+                      piece_sizes: list[int], expect: list[int],
+                      stage_addr: int) -> tuple[list[str], float] | None:
+    """``span_write`` that also stages: in the same call, on the same
+    thread and with the GIL dropped, each piece whose crc32c equals
+    ``expect[i]`` (-1: the piece carries no digest) is copied to
+    ``stage_addr + (its offset in data)``, AFTER its crc is known, so a
+    corrupt piece's bytes never arrive there. ``stage_addr`` is the
+    address of ``len(data)`` writable bytes that the caller keeps alive
+    across the call (``StageLease.address``). Returns the crc hex list
+    and the seconds the copies took, or None to signal fallback (no .so,
+    or one built before the export)."""
+    lib = load()
+    if lib is None or not getattr(lib, "_df_has_span_stage", False):
+        return None
+    ptr, n = _buf_arg(data)
+    if n != sum(piece_sizes) or len(expect) != len(piece_sizes):
+        raise ValueError(f"span buffer {n}, piece_sizes {piece_sizes} and "
+                         f"{len(expect)} expected crcs do not agree")
+    sizes = (ctypes.c_uint64 * len(piece_sizes))(*piece_sizes)
+    want = (ctypes.c_int64 * len(expect))(*expect)
+    crcs = (ctypes.c_uint32 * len(piece_sizes))()
+    stage_ns = ctypes.c_uint64(0)
+    rc = lib.df_span_write_staged(fd, offset, ptr, sizes, len(piece_sizes),
+                                  crcs, want, stage_addr,
+                                  ctypes.byref(stage_ns))
+    if rc < 0:
+        raise OSError(-rc, os.strerror(-rc))
+    return [f"{c:08x}" for c in crcs], stage_ns.value / 1e9
 
 
 def piece_read(path: str, offset: int, length: int) -> bytes | None:

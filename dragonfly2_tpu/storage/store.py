@@ -15,6 +15,12 @@ never the event loop.
 downloaded span costs ONE buffer traversal (pwrite + per-piece crc32c
 fused in the native library, or one pwrite + off-loop hashing in the
 Python fallback) and one write syscall chain instead of N of each.
+
+A task with a device sink hands its landings a ``stage`` (the sink's
+``tpu.hbm_sink.StageLease``): each piece is copied into the sink's host
+buffer on this thread, in the same hop, once it has verified and is about
+to be recorded, and only then. A corrupt piece's bytes never reach the
+sink and an already-recorded piece is not copied again.
 """
 
 from __future__ import annotations
@@ -49,6 +55,20 @@ def _pread_all(fd: int, length: int, offset: int) -> bytes:
         parts.append(b)
         got += len(b)
     return b"".join(parts)
+
+
+def _crc_expect(algo: str, want: str) -> int:
+    """What ``df_span_write_staged`` compares a piece's crc32c with: -1
+    for no digest, else the digest's value, or one that no crc equals when
+    ``want`` is not the eight lower-case hex digits that ``write_span``'s
+    own string comparison would accept."""
+    if not algo:
+        return -1
+    try:
+        value = int(want, 16)
+    except ValueError:
+        return 1 << 32
+    return value if f"{value:08x}" == want else 1 << 32
 
 
 def _pwrite_all(fd: int, data, offset: int) -> None:
@@ -161,8 +181,12 @@ class TaskStorage:
 
     def write_piece(self, num: int, offset: int, data: bytes | memoryview,
                     piece_digest: str = "", *, cost_ms: int = 0,
-                    source: str = "", pre_verified: bool = False) -> PieceMeta:
+                    source: str = "", pre_verified: bool = False,
+                    stage=None) -> PieceMeta:
         """Verify + persist one piece. Idempotent per piece number.
+
+        ``stage``: the device sink's lease (module docstring); the piece
+        is copied there once verified, unless it was already recorded.
 
         ``pre_verified`` skips the redundant re-hash when the transport
         already checked the bytes against ``piece_digest`` (the P2P
@@ -222,6 +246,8 @@ class TaskStorage:
             except OSError as exc:
                 raise DFError(Code.CLIENT_STORAGE_ERROR,
                               f"piece {num} write failed: {exc}") from None
+        if stage is not None:
+            stage.copy(offset, data)
         meta = PieceMeta(num=num, start=offset, size=len(data),
                          digest=piece_digest, cost_ms=cost_ms, source=source)
         with self._lock:
@@ -234,7 +260,8 @@ class TaskStorage:
 
     def write_span(self, pieces: list[tuple[int, int, int, str]], data,
                    *, base: int | None = None, cost_ms: int = 0,
-                   source: str = "") -> tuple[list[PieceMeta], list[int], str]:
+                   source: str = "", stage=None,
+                   ) -> tuple[list[PieceMeta], list[int], str]:
         """Land a whole contiguous downloaded span in ONE pass.
 
         ``pieces``: ``(num, offset, size, digest)`` in ascending offset
@@ -251,6 +278,11 @@ class TaskStorage:
         Already-recorded pieces (endgame duplicates) are skipped without
         being re-written: overwriting a verified region with a racer's
         unverified bytes would let a corrupt duplicate trash good data.
+
+        ``stage`` (the device sink's lease, module docstring) receives
+        the pieces this call records, and no others: inside the native
+        call, piece by piece as each crc matches, or by ``stage.copy``
+        once the slice has verified.
         """
         if base is None:
             base = pieces[0][1]
@@ -278,9 +310,18 @@ class TaskStorage:
                        for p in run]
             crc_capable = all(a in ("", "crc32c") for a, _ in digests)
             crcs = None
+            staged_s = None       # the native call staged: its copy seconds
             try:
                 with self._data_fd() as fd:
-                    if crc_capable:
+                    dest = (stage.address(run_off, run_len)
+                            if stage is not None and crc_capable else 0)
+                    if dest:
+                        done = native.span_write_staged(
+                            fd, run_off, run_view, sizes,
+                            [_crc_expect(*d) for d in digests], dest)
+                        if done is not None:
+                            crcs, staged_s = done
+                    if crcs is None and crc_capable:
                         crcs = native.span_write(fd, run_off,
                                                  run_view, sizes)
                     if crcs is None:
@@ -290,6 +331,7 @@ class TaskStorage:
                               f"span write @{run_off}+{run_len} failed: "
                               f"{exc}") from None
             pos = 0
+            n_metas = len(metas)
             for i, (num, off, size, dg) in enumerate(run):
                 piece_view = run_view[pos:pos + size]
                 pos += size
@@ -311,9 +353,14 @@ class TaskStorage:
                     else:
                         dg = digestlib.for_bytes(
                             digestlib.preferred_piece_algo(), piece_view)
+                if stage is not None and staged_s is None:
+                    stage.copy(off, piece_view)
                 metas.append(PieceMeta(num=num, start=off, size=size,
                                        digest=dg, cost_ms=cost_ms,
                                        source=source))
+            if staged_s is not None:
+                stage.account(staged_s,
+                              sum(m.size for m in metas[n_metas:]))
         with self._lock:
             for meta in metas:
                 self.md.pieces.setdefault(meta.num, meta)
@@ -549,7 +596,8 @@ class SubTaskStorage:
 
     def write_piece(self, num: int, offset: int, data: bytes | memoryview,
                     piece_digest: str = "", *, cost_ms: int = 0,
-                    source: str = "", pre_verified: bool = False) -> PieceMeta:
+                    source: str = "", pre_verified: bool = False,
+                    stage=None) -> PieceMeta:
         if offset + len(data) > self.md.range_length:
             raise DFError(Code.CLIENT_STORAGE_ERROR,
                           f"piece {num} spills past sub-range: "
@@ -571,6 +619,8 @@ class SubTaskStorage:
         except OSError as exc:
             raise DFError(Code.CLIENT_STORAGE_ERROR,
                           f"piece {num} write failed: {exc}") from None
+        if stage is not None:
+            stage.copy(offset, data)
         meta = PieceMeta(num=num, start=offset, size=len(data),
                          digest=piece_digest, cost_ms=cost_ms, source=source)
         with self._lock:

@@ -21,8 +21,10 @@ pod-level breach — so a chaos pipeline can gate on
 
 Waterfall legend: ``.`` queue (rate-limiter wait), ``-`` ttfb (request +
 parent-side queueing), ``=`` wire transfer, ``#`` landing + HBM staging
-(last byte off the wire to the piece staged for the sink: the storage
-thread's write + verify pass and its waits, then the staging copy).
+(last byte off the wire to the piece accounted in the sink: the storage
+thread's write + verify pass, the staging copy it makes in the same hop
+for a task with a device sink (``staged`` in the journal), its waits, then
+the sink's bookkeeping on the loop).
 """
 
 from __future__ import annotations
@@ -130,9 +132,9 @@ def verdict(summary: dict) -> str:
         # hbm_ms is landing + staging: say which of the two it was
         share = 100 * staged / stage_totals["hbm_ms"]
         parts.append(
-            f"of landing + HBM staging, {100 - share:.0f}% was landing "
-            "(the storage thread's write + verify and its waits) and "
-            f"{share:.0f}% the staging copy on the daemon loop")
+            f"of landing + HBM staging, {100 - share:.0f}% was the storage "
+            "thread's (write + verify, the staging copy, and its waits) "
+            f"and {share:.0f}% the sink's bookkeeping on the daemon loop")
     sec = summary.get("sections_ms") or {}
     lived = sec.get("worker_wait", 0.0) + sec.get("worker_busy", 0.0)
     if lived > 0:

@@ -4,9 +4,13 @@ download.
 This is the TPU-native replacement for the GPUDirect/pinned-CUDA-memory role
 in GPU-side distribution stacks (see BASELINE.json north star). Design:
 
-- Pieces are written into a preallocated host ``numpy`` buffer (the pinned
-  staging area) at their content offsets, zero extra copies in Python
-  (memoryview slicing).
+- Verified pieces are copied into a host ``numpy`` buffer (the staging
+  area) at their content offsets. The copy runs on the storage thread
+  that landed the piece, in the same hop (``StageLease``); the sink's
+  bookkeeping (``commit``) follows on the caller's thread. The buffer is
+  leased from a pool of released sink buffers (``SinkBufferPool``), so
+  the copy writes pages that are already there instead of faulting a
+  file's worth of fresh ones in.
 - The content is split into ``shard_count`` contiguous byte shards. The
   moment every byte of a shard is present, that shard's index is enqueued to
   a dedicated transfer thread that owns every ``jax.device_put`` call.
@@ -14,8 +18,7 @@ in GPU-side distribution stacks (see BASELINE.json north star). Design:
   ``device_put`` of an unpinned host buffer is synchronous (it blocks the
   caller for the whole staging copy + DMA), so dispatching it from the
   asyncio event loop or awaiting it from the piece-landing path stalls the
-  daemon's own sockets. The worker thread absorbs that blocking; the landing
-  path only memcpys.
+  daemon's own sockets. The worker thread absorbs that blocking.
 - ``result()`` drains the transfer queue, blocks until the DMAs finish, and
   assembles per-device shards into ONE logically-global jax.Array via
   ``jax.make_array_from_single_device_arrays`` when a mesh sharding is
@@ -54,6 +57,185 @@ _hbm_bytes = REGISTRY.counter(
     "df_hbm_staged_bytes_total", "bytes staged into the host buffer")
 _hbm_queue = REGISTRY.gauge(
     "df_hbm_transfer_queue_depth", "shard transfers enqueued, not yet done")
+_pool_acquires = REGISTRY.counter(
+    "df_sinkpool_acquires_total", "sink host-buffer pool leases", ("result",))
+_pool_parked = REGISTRY.gauge(
+    "df_sinkpool_bytes", "bytes parked in the sink host-buffer pool")
+
+
+class SinkBufferPool:
+    """Recycles the sinks' file-sized host buffers, as ``common/bufpool``
+    recycles the 4-16 MiB piece buffers and for the same reason: a fresh
+    buffer's every page is a first-touch fault under the staging copy
+    (about 1 GB/s into fresh pages against 6 into touched ones).
+
+    Contract:
+
+    * ``acquire(size)`` returns ``(buffer, hit)``: a uint8 array of AT
+      LEAST ``size`` bytes, the smallest parked one that fits (a 640 MiB
+      lease fits a parked 1,056 MiB buffer), else a fresh one. Contents
+      are undefined: the sink zeroes its pad tail and transfers nothing
+      else that ``CoverageMap`` has not seen written.
+    * ``release(buffer, recycle)`` ends the lease, exactly once. The
+      holder passes ``recycle`` only when nothing can read or write the
+      buffer any more (``DeviceIngest._release_host``).
+    * Parked bytes are bounded by what the sinks themselves held: parked
+      plus leased bytes never exceed the high-water mark of leased bytes,
+      so the pool keeps what the process held a moment earlier and nothing
+      beyond. A lease that no parked buffer fits drops the smallest parked
+      ones until its fresh buffer fits under that mark.
+
+    Thread-safe: leases start on the loop, end on transfer and storage
+    threads."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._parked: list[np.ndarray] = []    # ascending by size
+        self._parked_bytes = 0
+        self._leased_bytes = 0
+        self._leased_peak = 0
+
+    def _park(self, delta: int) -> None:
+        self._parked_bytes += delta
+        _pool_parked.inc(delta)    # a delta: tests make pools of their own
+
+    def acquire(self, size: int) -> tuple[np.ndarray, bool]:
+        with self._lock:
+            fit = next((i for i, b in enumerate(self._parked)
+                        if b.nbytes >= size), None)
+            if fit is not None:
+                buf = self._parked.pop(fit)
+                self._park(-buf.nbytes)
+            self._leased_bytes += size if fit is None else buf.nbytes
+            self._leased_peak = max(self._leased_peak, self._leased_bytes)
+            while self._parked and (self._parked_bytes + self._leased_bytes
+                                    > self._leased_peak):
+                self._park(-self._parked.pop(0).nbytes)
+        if fit is not None:
+            _pool_acquires.labels("hit").inc()
+            return buf, True
+        _pool_acquires.labels("miss").inc()
+        return np.empty(size, dtype=np.uint8), False
+
+    def release(self, buf: np.ndarray, recycle: bool) -> None:
+        with self._lock:
+            self._leased_bytes -= buf.nbytes
+            if recycle and (self._parked_bytes + self._leased_bytes
+                            + buf.nbytes <= self._leased_peak):
+                self._parked.append(buf)
+                self._parked.sort(key=lambda b: b.nbytes)
+                self._park(buf.nbytes)
+
+    def parked_bytes(self) -> int:
+        with self._lock:
+            return self._parked_bytes
+
+
+# process-wide, as bufpool.POOL is: every sink of the daemon shares it
+HOST_POOL = SinkBufferPool()
+
+
+class StageLease:
+    """One landing's hold on a sink's host buffer: what the storage layer
+    is handed (``write_span(..., stage=)``) to copy VERIFIED pieces into
+    the sink on its own thread, in the landing's hop. While a lease is
+    out the sink neither frees nor recycles the buffer, whatever happens
+    to the sink meanwhile (``close``, a lost sink, the last transfer).
+
+    ``address`` serves the native landing (``df_span_write_staged``
+    copies each piece once its crc has matched), ``copy`` the Python
+    ones; both touch only ``host[offset:end]`` and no bookkeeping, so any
+    number of landings stage at once over their disjoint ranges. A
+    failure is kept in ``error`` and never raised into the landing: the
+    bytes still finish landing on disk, and the caller loses the sink
+    when it accounts the piece (``conductor._ingest_to_device``).
+    ``seconds`` and ``nbytes`` are the copies this lease made (flight
+    ``staged``). Once the sink has let its buffer go (every shard is on
+    the device, or it was closed) a lease is inert and copies nothing.
+
+    A sink whose ``write`` is not ``DeviceIngest``'s own (a subclass, a
+    test's double, the benchmark's planted fault) is handed every piece
+    through that ``write``, whole, on the same thread: nothing goes past
+    it, the native copy included."""
+
+    __slots__ = ("_ingest", "_host", "_via_write", "seconds", "nbytes",
+                 "error")
+
+    def __init__(self, ingest: "DeviceIngest", host: np.ndarray | None):
+        self._ingest = ingest
+        self._host = host
+        self._via_write = type(ingest).write is not _OWN_WRITE
+        self.seconds = 0.0
+        self.nbytes = 0
+        self.error: Exception | None = None
+
+    def _check(self, offset: int, length: int) -> None:
+        if offset < 0 or offset + length > self._ingest.content_length:
+            raise ValueError(f"write beyond content: {offset + length} > "
+                             f"{self._ingest.content_length}")
+
+    def address(self, offset: int, length: int) -> int:
+        """Where ``length`` bytes at content ``offset`` belong in the host
+        buffer, for native code to copy to; 0 when the copy is not native
+        code's to make (an inert lease, a replaced ``write``, or a range
+        beyond the content, which is kept as the lease's error)."""
+        if self._host is None or self.error is not None or self._via_write:
+            return 0
+        try:
+            self._check(offset, length)
+        except ValueError as exc:
+            self.error = exc
+            return 0
+        return self._host.ctypes.data + offset
+
+    def took(self, since: int) -> bool:
+        """Whether a landing staged what it was given: ``nbytes`` moved
+        past ``since``, or could not have (a failed or inert lease). False
+        is a landing that skipped its piece as already recorded, whose
+        verified bytes are on disk and were copied nowhere."""
+        return (self.nbytes > since or self.error is not None
+                or self._ingest.host is None)
+
+    def account(self, seconds: float, nbytes: int) -> None:
+        """Copies that native code made through ``address``."""
+        self.seconds += seconds
+        self.nbytes += nbytes
+        _hbm_bytes.inc(nbytes)
+
+    def _copy(self, offset: int, data) -> None:
+        self._check(offset, len(data))
+        with tracing.annotate("stage_copy"):
+            self._host[offset:offset + len(data)] = np.frombuffer(
+                data, dtype=np.uint8)
+        _hbm_bytes.inc(len(data))
+
+    def copy(self, offset: int, data) -> None:
+        """The staging copy of one verified piece. Keeps no reference to
+        ``data`` past its return (the piece-buffer pool depends on it)."""
+        if self._host is None or self.error is not None:
+            return
+        t0 = time.perf_counter()
+        try:
+            if self._via_write:
+                self._ingest.write(offset, data)
+            else:
+                self._copy(offset, data)
+        except Exception as exc:  # noqa: BLE001 - the caller loses the sink
+            self.error = exc
+            return
+        self.seconds += time.perf_counter() - t0
+        self.nbytes += len(data)
+
+    def release(self) -> None:
+        if self._host is not None:
+            self._host = None
+            self._ingest._end_lease()
+
+    def __enter__(self) -> "StageLease":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.release()
 
 
 class CoverageMap:
@@ -113,7 +295,8 @@ class DeviceIngest:
                  shards_per_device: int = 1,
                  shard_specs: list | None = None,
                  on_shard_ready: Callable[[str, float], None] | None = None,
-                 device_put_fn: Callable[[Any, Any], Any] | None = None):
+                 device_put_fn: Callable[[Any, Any], Any] | None = None,
+                 pool: SinkBufferPool | None = None):
         """``devices``: explicit device list (contiguous shards per device),
         or ``sharding``: a 1-D jax NamedSharding to assemble a global array
         on. ``shards_per_device`` > 1 pipelines the host->HBM DMA: each
@@ -137,7 +320,9 @@ class DeviceIngest:
         needs the equal-split geometry). ``on_shard_ready`` is called ON
         THE TRANSFER THREAD as ``(name, monotonic_done_time)`` after each
         named shard's device transfer completes — callbacks must be cheap
-        and thread-safe (hand off to the loop, don't compute)."""
+        and thread-safe (hand off to the loop, don't compute). ``pool``:
+        where the host buffer is leased from (the process's ``HOST_POOL``;
+        tests pass their own)."""
         import jax
 
         if content_length <= 0:
@@ -182,7 +367,6 @@ class DeviceIngest:
             # overlap scan order: (start, end, index) sorted by start
             self._spec_order = sorted(
                 (sp[1], sp[1] + sp[2], i) for i, sp in enumerate(specs))
-            self.host = np.zeros(content_length, dtype=np.uint8)
         else:
             n = len(self.devices) * self.shards_per_device
             self.n_shards = n
@@ -191,7 +375,6 @@ class DeviceIngest:
             padded = -(-content_length // (n * itemsize)) * (n * itemsize)
             self.padded_length = padded
             self.shard_bytes = padded // n
-            self.host = np.zeros(padded, dtype=np.uint8)
         self._coverage = CoverageMap()
         self._shard_arrays: list[Any | None] = [None] * n
         self._shard_sent = [False] * n       # transfer COMPLETED
@@ -208,6 +391,17 @@ class DeviceIngest:
         self._idle.set()
         self._error: BaseException | None = None
         self._closed = False
+        # the host buffer: a lease on a pooled buffer at least as long,
+        # dirty but for the pad tail (every other byte is covered before
+        # its shard may be enqueued, and gaps never transfer). It goes
+        # back once nothing can touch it: see _release_host
+        self._pool = pool if pool is not None else HOST_POOL
+        self._backing, self.pool_hit = self._pool.acquire(self.padded_length)
+        self.host: np.ndarray | None = self._backing[:self.padded_length]
+        self.host[content_length:] = 0
+        self._leases = 0                     # StageLeases out
+        self._worker_done = False
+        self._host_aliased = False           # a device array reads it in place
         self._worker = threading.Thread(target=self._transfer_loop,
                                         name="hbm-sink", daemon=True)
         self._worker.start()
@@ -222,27 +416,48 @@ class DeviceIngest:
     # producer side (piece-landing path) — never blocks on DMA
     # ------------------------------------------------------------------
 
-    def write(self, offset: int, data: bytes | memoryview) -> None:
-        """Land one verified piece; enqueues device transfers for any shard
-        the piece completes. Returns as soon as the memcpy is done.
+    def lease(self) -> StageLease:
+        """A landing's hold on the host buffer (see ``StageLease``); the
+        caller releases it when the landing has returned."""
+        with self._lock:
+            host = self.host
+            if host is not None:
+                self._leases += 1
+        return StageLease(self, host)
 
-        Buffer lifetime rule (the piece-buffer pool depends on it): this
-        method NEVER retains a reference to ``data`` past its return. The
-        numpy assignment below copies into the sink's own host buffer and
-        the transient ``frombuffer`` view dies with the statement — so the
-        landing path may recycle the piece buffer (bufpool.POOL.release)
-        the moment its landing call stack unwinds. Device transfers read
-        ONLY ``self.host``, never the caller's buffer."""
+    def _end_lease(self) -> None:
+        with self._lock:
+            self._leases -= 1
+            self._release_host()
+
+    def _release_host(self) -> None:
+        """Let the host buffer go once no reader or writer of it can
+        exist: the transfer worker has exited (every shard's
+        ``device_put`` has returned from ``block_until_ready``, or the
+        sink was closed and the transfers queued before that are through)
+        and no landing holds a lease. It is recycled unless a device array
+        reads it in place. Called under ``_lock``."""
+        if (self._worker_done and not self._leases
+                and self._backing is not None):
+            backing, self._backing, self.host = self._backing, None, None
+            self._pool.release(
+                backing,
+                recycle=not self._host_aliased and self._error is None)
+
+    def commit(self, offset: int, nbytes: int) -> None:
+        """The bookkeeping for one staged piece: marks ``[offset, offset +
+        nbytes)`` present and enqueues the device transfer of every shard
+        that completes. Call it only after the piece's staging copy has
+        returned (the landing that held the ``StageLease`` has): a shard
+        is transferred the moment its range is covered."""
         if faultgate.ARMED:
             # a raising script here exercises the conductor's sink-loss
             # path: the bytes finish landing on disk, the task FAILS
             faultgate.fire_sync("hbm.ingest")
-        end = offset + len(data)
+        end = offset + nbytes
         if end > self.content_length:
             raise ValueError(f"write beyond content: {end} > {self.content_length}")
-        self.host[offset:end] = np.frombuffer(data, dtype=np.uint8)
         self._coverage.add(offset, end)
-        _hbm_bytes.inc(len(data))
         if self._specs is not None:
             # manifest mode: enqueue every named range this span touches
             # (a piece straddling a shard boundary can complete two)
@@ -257,6 +472,22 @@ class DeviceIngest:
         last = (end - 1) // self.shard_bytes
         for shard in range(first, min(last + 1, self.n_shards)):
             self._maybe_enqueue(shard)
+
+    def write(self, offset: int, data: bytes | memoryview) -> None:
+        """Stage one verified piece and account it, on the calling thread:
+        the staging copy, then ``commit``. For callers that already hold
+        verified bytes off the loop (``ShardPrefetcher``'s re-ingest from
+        storage); the download path stages inside the landing instead.
+
+        Buffer lifetime rule (the piece-buffer pool depends on it): no
+        reference to ``data`` outlives the staging copy, which is
+        complete when this returns — as it is, for a landing, when the
+        landing returns. Device transfers read ONLY the sink's host
+        buffer, never the caller's."""
+        with self.lease() as lease:
+            if lease._host is not None:
+                lease._copy(offset, data)
+        self.commit(offset, len(data))
 
     def _shard_range(self, shard: int) -> tuple[int, int]:
         if self._specs is not None:
@@ -296,64 +527,100 @@ class DeviceIngest:
     # ------------------------------------------------------------------
 
     def _transfer_loop(self) -> None:
-        while True:
-            shard = self._queue.get()
-            if shard is None:            # shutdown sentinel
-                return
+        try:
+            while True:
+                shard = self._queue.get()
+                # None: shutdown sentinel
+                if shard is None or self._transfer(shard):
+                    return
+        finally:
+            with self._lock:
+                self._worker_done = True
+                self._release_host()
+
+    def _reads_host_in_place(self, arr: Any, device: Any) -> bool:
+        """Whether a transferred array is a view of the host buffer and
+        not a copy of it: ``jax.device_put`` on the CPU backend returns
+        one for a 64-byte-aligned source. Such a buffer is never
+        recycled. Observed, not configured: device memory is not host
+        memory; on a host-memory backend the array's buffer pointer says;
+        where nothing says, it is taken to alias."""
+        backing = self._backing
+        lo = backing.ctypes.data
+        if isinstance(arr, np.ndarray):
+            ptr = arr.ctypes.data
+        elif getattr(device, "platform", "cpu") != "cpu":
+            return False
+        else:
             try:
-                s, e = self._shard_range(shard)
-                if self._specs is not None:
-                    name, _s, _size, sdtype, shape = self._specs[shard]
-                    view = self.host[s:e].view(sdtype)
-                    if shape is not None:
-                        view = view.reshape(shape)
-                    device = self.devices[shard % len(self.devices)]
-                else:
-                    name = None
-                    view = self.host[s:e].view(self.dtype)
-                    device = self.devices[shard // self.shards_per_device]
-                t0 = time.monotonic()
-                with tracing.annotate("hbm_transfer"):
-                    arr = self._device_put(view, device)
-                    # span must end at transfer COMPLETION, not dispatch —
-                    # on backends where device_put returns before the DMA
-                    # lands, a dispatch-end span would report overlap that
-                    # never ran
-                    wait = getattr(arr, "block_until_ready", None)
-                    if wait is not None:
-                        wait()
-                t1 = time.monotonic()
-                with self._lock:
-                    self._shard_arrays[shard] = arr
-                    self._shard_sent[shard] = True
-                    self.transfer_spans.append((t0, t1))
-                _hbm_transfer_s.observe(t1 - t0)
-                _hbm_transfers.labels("ok").inc()
-                if name is not None and self.on_shard_ready is not None:
-                    try:
-                        self.on_shard_ready(name, t1)
-                    except Exception:  # noqa: BLE001 - observer only
-                        log.exception("on_shard_ready(%s) raised", name)
-                log.debug("shard %d/%d -> %s", shard, self.n_shards, device)
-            except BaseException as exc:  # noqa: BLE001 - surfaced by result()
-                with self._lock:
-                    if self._error is None:
-                        self._error = exc
-                _hbm_transfers.labels("fail").inc()
-                log.exception("device transfer of shard %d failed", shard)
-            finally:
-                with self._lock:
-                    self._pending -= 1
-                    _hbm_queue.dec()
-                    if self._pending == 0:
-                        self._idle.set()
-                    # self-terminate once every shard has shipped: a consumer
-                    # that never calls result()/close() (task finished, nobody
-                    # collected) must not leak this thread + the file-sized
-                    # host buffer it pins for the daemon's lifetime
-                    if all(self._shard_sent):
-                        self._closed = True
-                        return
+                ptr = arr.unsafe_buffer_pointer()
+            except Exception:  # noqa: BLE001 - unknown: do not recycle
+                return True
+        return lo <= ptr < lo + backing.nbytes
+
+    def _transfer(self, shard: int) -> bool:
+        """One shard onto its device; True once every shard has shipped."""
+        try:
+            s, e = self._shard_range(shard)
+            if self._specs is not None:
+                name, _s, _size, sdtype, shape = self._specs[shard]
+                view = self.host[s:e].view(sdtype)
+                if shape is not None:
+                    view = view.reshape(shape)
+                device = self.devices[shard % len(self.devices)]
+            else:
+                name = None
+                view = self.host[s:e].view(self.dtype)
+                device = self.devices[shard // self.shards_per_device]
+            t0 = time.monotonic()
+            with tracing.annotate("hbm_transfer"):
+                arr = self._device_put(view, device)
+                # span must end at transfer COMPLETION, not dispatch —
+                # on backends where device_put returns before the DMA
+                # lands, a dispatch-end span would report overlap that
+                # never ran
+                wait = getattr(arr, "block_until_ready", None)
+                if wait is not None:
+                    wait()
+            t1 = time.monotonic()
+            if not self._host_aliased:
+                self._host_aliased = self._reads_host_in_place(arr, device)
+            with self._lock:
+                self._shard_arrays[shard] = arr
+                self._shard_sent[shard] = True
+                self.transfer_spans.append((t0, t1))
+            _hbm_transfer_s.observe(t1 - t0)
+            _hbm_transfers.labels("ok").inc()
+            if name is not None and self.on_shard_ready is not None:
+                try:
+                    self.on_shard_ready(name, t1)
+                except Exception:  # noqa: BLE001 - observer only
+                    log.exception("on_shard_ready(%s) raised", name)
+            log.debug("shard %d/%d -> %s", shard, self.n_shards, device)
+        except BaseException as exc:  # noqa: BLE001 - surfaced by result()
+            with self._lock:
+                if self._error is None:
+                    self._error = exc
+            _hbm_transfers.labels("fail").inc()
+            log.exception("device transfer of shard %d failed", shard)
+        finally:
+            with self._lock:
+                self._pending -= 1
+                _hbm_queue.dec()
+                # self-terminate once every shard has shipped: a consumer
+                # that never calls result()/close() (task finished, nobody
+                # collected) must not leak this thread, nor keep the
+                # file-sized host buffer out of the pool. The buffer goes
+                # before drain() is woken, so that the next task's sink
+                # finds it parked
+                done = all(self._shard_sent)
+                if done:
+                    self._closed = True
+                    self._worker_done = True
+                    self._release_host()
+                if self._pending == 0:
+                    self._idle.set()
+        return done
 
     # ------------------------------------------------------------------
     # consumer side
@@ -407,7 +674,10 @@ class DeviceIngest:
             # leave the thread parked on queue.get holding the host buffer
             self.close()
         for a in arrays:
-            a.block_until_ready()
+            # (an injected device_put_fn may hand back plain numpy)
+            wait = getattr(a, "block_until_ready", None)
+            if wait is not None:
+                wait()
         if self._specs is not None:
             return {sp[0]: arrays[i] for i, sp in enumerate(self._specs)}
         if self._sharding is None:
@@ -415,3 +685,7 @@ class DeviceIngest:
         global_shape = (self.padded_length // self.dtype.itemsize,)
         return jax.make_array_from_single_device_arrays(
             global_shape, self._sharding, arrays)
+
+
+# what StageLease compares a sink's ``write`` with, to see it replaced
+_OWN_WRITE = DeviceIngest.write

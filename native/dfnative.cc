@@ -11,6 +11,7 @@
 #include <cstdint>
 #include <cstdio>
 #include <cstring>
+#include <ctime>
 #include <fcntl.h>
 #include <unistd.h>
 #include <cerrno>
@@ -333,10 +334,18 @@ int df_piece_write(const char* path, uint64_t offset, const uint8_t* data,
 // rejected piece are on disk but never recorded, so the region stays
 // "absent" and the retry re-writes it — same safety story as
 // df_piece_write). Returns 0, or -errno on IO failure.
-int df_span_write(int fd, uint64_t offset, const uint8_t* data,
-                  const uint64_t* piece_sizes, size_t n_pieces,
-                  uint32_t* crcs_out) {
+//
+// With a staging destination (a device sink's host buffer at this span's
+// content offset) each piece is also copied there, AFTER its crc is known
+// and only when it matches expect[i] (-1: the piece carries no digest, its
+// crc becomes one): a corrupt piece's bytes never reach the sink. The
+// seconds the copies took come back in *stage_ns_out.
+static int span_write(int fd, uint64_t offset, const uint8_t* data,
+                      const uint64_t* piece_sizes, size_t n_pieces,
+                      uint32_t* crcs_out, const int64_t* expect,
+                      uint8_t* stage_dst, uint64_t* stage_ns_out) {
   size_t pos = 0;
+  uint64_t stage_ns = 0;
   const size_t kChunk = 4u << 20;
   for (size_t i = 0; i < n_pieces; i++) {
     size_t n = (size_t)piece_sizes[i];
@@ -354,9 +363,36 @@ int df_span_write(int fd, uint64_t offset, const uint8_t* data,
       done += (size_t)w;
     }
     if (crcs_out) crcs_out[i] = crc;
+    if (stage_dst && (!expect || expect[i] < 0 ||
+                      expect[i] == (int64_t)crc)) {
+      struct timespec t0, t1;
+      clock_gettime(CLOCK_MONOTONIC, &t0);
+      memcpy(stage_dst + pos, data + pos, n);
+      clock_gettime(CLOCK_MONOTONIC, &t1);
+      stage_ns += (uint64_t)((t1.tv_sec - t0.tv_sec) * 1000000000LL +
+                             (t1.tv_nsec - t0.tv_nsec));
+    }
     pos += n;
   }
+  if (stage_ns_out) *stage_ns_out = stage_ns;
   return 0;
+}
+
+int df_span_write(int fd, uint64_t offset, const uint8_t* data,
+                  const uint64_t* piece_sizes, size_t n_pieces,
+                  uint32_t* crcs_out) {
+  return span_write(fd, offset, data, piece_sizes, n_pieces, crcs_out,
+                    nullptr, nullptr, nullptr);
+}
+
+// df_span_write with the staging destination: a separate export so that a
+// library built before it keeps its working span landing.
+int df_span_write_staged(int fd, uint64_t offset, const uint8_t* data,
+                         const uint64_t* piece_sizes, size_t n_pieces,
+                         uint32_t* crcs_out, const int64_t* expect,
+                         uint8_t* stage_dst, uint64_t* stage_ns_out) {
+  return span_write(fd, offset, data, piece_sizes, n_pieces, crcs_out,
+                    expect, stage_dst, stage_ns_out);
 }
 
 // pread() a piece straight into the caller's buffer (no Python file

@@ -289,8 +289,11 @@ class TestReuseSafety:
         st = conductor.storage
         for num in range(n_pieces):
             assert st.read_piece(num) == blob[num * piece:(num + 1) * piece]
-        # sink host staging intact (every DMA read only sink-owned memory)
-        assert bytes(sink.host[:len(blob)]) == blob
+        # what the sink staged and transferred is intact (every DMA read
+        # only sink-owned memory; the host buffer itself went back to the
+        # sink pool with the last transfer)
+        assert sink.host is None
+        assert b"".join(bytes(a) for a in sink._shard_arrays) == blob
         sink.close()
         st.close()
 
@@ -402,6 +405,221 @@ class TestEndgameRaceSafety:
             first.storage.close()
 
         asyncio.run(go())
+
+
+class TestStagingInTheLanding:
+    """The staging copy into the device sink rides the landing: on the
+    storage thread, for verified pieces, with the sink's buffer held for
+    as long as a landing can touch it; what the loop does afterwards is
+    the sink's bookkeeping."""
+
+    PIECE = 64 * 1024
+
+    def _conductor(self, tmp_path, blob, pool, **sink_kw):
+        import numpy as np
+
+        from dragonfly2_tpu.daemon.conductor import PeerTaskConductor
+        from dragonfly2_tpu.daemon.flight_recorder import FlightRecorder
+        from dragonfly2_tpu.tpu.hbm_sink import DeviceIngest
+
+        stores = self.__dict__.setdefault("_stores", {})
+
+        class _Mgr:
+            def register_task(self, md):        # one store a directory
+                return stores.setdefault(
+                    tmp_path, TaskStorage(str(tmp_path / "task"), md))
+
+        def factory(n):
+            return DeviceIngest(
+                n, devices=[object()], pool=pool,
+                device_put_fn=lambda v, d: np.array(v, copy=True),
+                **sink_kw)
+
+        flight = FlightRecorder().begin("g" * 64, "stage-peer")
+        c = PeerTaskConductor(
+            task_id="g" * 64, peer_id="stage-peer", url="test://stage",
+            url_meta=None, storage_mgr=_Mgr(), piece_mgr=None,
+            device_sink_factory=factory, flight=flight)
+        c.set_content_info(len(blob), self.PIECE)
+        return c
+
+    def _infos(self, blob):
+        from dragonfly2_tpu.idl.messages import PieceInfo
+
+        return [PieceInfo(piece_num=i, range_start=off,
+                          range_size=len(blob[off:off + self.PIECE]),
+                          digest=digestlib.for_bytes(
+                              _algo(), blob[off:off + self.PIECE]))
+                for i, off in enumerate(range(0, len(blob), self.PIECE))]
+
+    def _staged(self, conductor):
+        return [e for e in conductor.flight.events if e[1] == "staged"]
+
+    def test_the_copy_runs_on_the_storage_thread_the_loop_only_accounts(
+            self, tmp_path, monkeypatch):
+        from dragonfly2_tpu.tpu import hbm_sink
+
+        blob = os.urandom(4 * self.PIECE)
+        pool = hbm_sink.SinkBufferPool()
+        conductor = self._conductor(tmp_path, blob, pool)
+        sink = conductor.device_ingest
+        where = []
+        real_address = hbm_sink.StageLease.address
+        real_copy = hbm_sink.StageLease.copy
+        real_commit = hbm_sink.DeviceIngest.commit
+
+        def address(self, *a):
+            where.append(("stage", threading.current_thread().name))
+            return real_address(self, *a)
+
+        def copy(self, *a):
+            where.append(("stage", threading.current_thread().name))
+            return real_copy(self, *a)
+
+        def commit(self, *a):
+            where.append(("commit", threading.current_thread().name))
+            return real_commit(self, *a)
+
+        monkeypatch.setattr(hbm_sink.StageLease, "address", address)
+        monkeypatch.setattr(hbm_sink.StageLease, "copy", copy)
+        monkeypatch.setattr(hbm_sink.DeviceIngest, "commit", commit)
+
+        async def go():
+            placed, corrupt, raced = await conductor.on_span_from_peer(
+                "parent", self._infos(blob), blob, 1)
+            assert sorted(placed) == [0, 1, 2, 3] and not corrupt
+            await asyncio.to_thread(sink.drain, 10)
+
+        asyncio.run(go())
+        me = threading.current_thread().name
+        assert {t for k, t in where if k == "commit"} == {me}
+        staged_on = {t for k, t in where if k == "stage"}
+        assert staged_on and all(t.startswith("df-storage")
+                                 for t in staged_on)
+        assert b"".join(bytes(a) for a in sink._shard_arrays) == blob
+        (ev,) = self._staged(conductor)
+        assert ev[4] == len(blob) and ev[5] > 0
+        done = [e for e in conductor.flight.events if e[1] == "hbm_done"]
+        assert sum(e[4] for e in done) == len(blob)
+        assert sink.host is None and pool.parked_bytes() == len(blob)
+        conductor.storage.close()
+
+    def test_a_sink_lost_mid_landing_keeps_its_buffer_for_the_landing(
+            self, tmp_path):
+        from dragonfly2_tpu.tpu.hbm_sink import SinkBufferPool
+
+        blob = os.urandom(2 * self.PIECE)
+        pool = SinkBufferPool()
+        conductor = self._conductor(tmp_path, blob, pool)
+        sink = conductor.device_ingest
+        st = conductor.storage
+        entered, gate = threading.Event(), threading.Event()
+        real_write_span = st.write_span
+
+        def slow_write_span(*a, **k):
+            entered.set()
+            gate.wait(10)
+            return real_write_span(*a, **k)
+
+        st.write_span = slow_write_span
+
+        async def go():
+            landing = asyncio.get_running_loop().create_task(
+                conductor.on_span_from_peer("parent", self._infos(blob),
+                                            blob, 1))
+            await asyncio.to_thread(entered.wait, 10)
+            conductor._sink_lost("lost in the test")
+            assert conductor.device_ingest is None
+            await asyncio.to_thread(sink._worker.join, 5)
+            # closed, its worker gone, and the buffer still the landing's
+            assert not sink._worker.is_alive()
+            assert sink.host is not None and pool.parked_bytes() == 0
+            gate.set()
+            placed, corrupt, raced = await landing
+            # the bytes finished landing on disk; the request has lost
+            assert sorted(placed) == [0, 1] and not corrupt and not raced
+
+        asyncio.run(go())
+        assert bytes(st.read_piece(0) + st.read_piece(1)) == blob
+        assert conductor.sink_error == "lost in the test"
+        assert sink.host is None and pool.parked_bytes() == len(blob)
+        st.close()
+
+    def test_a_failing_ingest_loses_the_sink_after_the_landing(
+            self, tmp_path):
+        """faultgate ``hbm.ingest`` raising: the piece's landing has
+        returned (its copy with it) before the sink is lost, so the
+        buffer goes back only then, and the bytes are on disk."""
+        from dragonfly2_tpu.common import faultgate
+        from dragonfly2_tpu.tpu.hbm_sink import SinkBufferPool
+
+        blob = os.urandom(3 * self.PIECE)
+        pool = SinkBufferPool()
+        conductor = self._conductor(tmp_path, blob, pool)
+        sink = conductor.device_ingest
+        faultgate.reset()
+        faultgate.arm("hbm.ingest", "fail", code=Code.INTERNAL, n=1)
+        try:
+            async def go():
+                placed, corrupt, _ = await conductor.on_span_from_peer(
+                    "parent", self._infos(blob), blob, 1)
+                assert sorted(placed) == [0, 1, 2] and not corrupt
+                await asyncio.to_thread(sink._worker.join, 5)
+
+            asyncio.run(go())
+        finally:
+            faultgate.reset()
+        assert "device ingest write failed at piece 0" in conductor.sink_error
+        assert conductor.device_ingest is None
+        (ev,) = self._staged(conductor)
+        assert ev[4] == len(blob)             # staged before the loss
+        assert sink.host is None and pool.parked_bytes() == len(blob)
+        st = conductor.storage
+        assert b"".join(st.read_piece(i) for i in range(3)) == blob
+        st.close()
+
+    @pytest.mark.parametrize("entry", ["span", "piece"])
+    def test_a_piece_recorded_earlier_is_staged_from_disk(self, tmp_path,
+                                                          entry):
+        """A retry over surviving storage: the racer's bytes of a recorded
+        piece were never checked, the store's were. The sink gets the
+        store's, read and copied on the storage thread."""
+        from dragonfly2_tpu.tpu.hbm_sink import SinkBufferPool
+
+        blob = os.urandom(2 * self.PIECE)
+        infos = self._infos(blob)
+        pool = SinkBufferPool()
+        first = self._conductor(tmp_path, blob, pool)
+        first.storage.write_piece(0, 0, blob[:self.PIECE], infos[0].digest)
+        first.device_ingest.close()
+        conductor = self._conductor(tmp_path, blob, pool)
+        assert not conductor.ready
+        sink = conductor.device_ingest
+        wire = bytearray(blob)
+        wire[11] ^= 0x10                      # piece 0 arrives altered
+
+        async def go():
+            if entry == "span":
+                placed, corrupt, _ = await conductor.on_span_from_peer(
+                    "parent", infos, bytes(wire), 1)
+                assert sorted(placed) == [0, 1] and not corrupt
+            else:
+                assert await conductor._land_piece(
+                    0, 0, bytes(wire[:self.PIECE]), 1, source="parent",
+                    piece_digest=infos[0].digest)
+                assert await conductor._land_piece(
+                    1, self.PIECE, blob[self.PIECE:], 1, source="parent",
+                    piece_digest=infos[1].digest)
+            await asyncio.to_thread(sink.drain, 10)
+
+        asyncio.run(go())
+        assert not conductor.sink_error
+        assert b"".join(bytes(a) for a in sink._shard_arrays) == blob
+        by_path = {e[3]: e[4] for e in self._staged(conductor)}
+        assert by_path["disk"] == self.PIECE
+        assert sum(by_path.values()) == len(blob)
+        assert conductor.storage is first.storage
+        conductor.storage.close()
 
 
 class TestUploadLimiterOrder:

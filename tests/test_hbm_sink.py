@@ -338,3 +338,356 @@ class TestMesh:
         assert mesh.shape["data"] == n // 2
         with pytest.raises(ValueError):
             make_mesh({"data": 3}) if n % 3 else (_ for _ in ()).throw(ValueError())
+
+
+# ----------------------------------------------------------------------
+# the staging copy off the loop, into a recycled host buffer
+# ----------------------------------------------------------------------
+
+from dragonfly2_tpu.tpu.hbm_sink import (  # noqa: E402
+    HOST_POOL, SinkBufferPool, StageLease)
+
+
+def _copying_put(view, device):
+    """A transfer that copies, as a chip's does: the array owns its bytes."""
+    return np.array(view, copy=True)
+
+
+def _dirty_pool(nbytes: int, fill: int = 0xFF) -> SinkBufferPool:
+    """A pool with one released buffer of ``nbytes``, every byte ``fill``."""
+    pool = SinkBufferPool()
+    buf, hit = pool.acquire(nbytes)
+    assert not hit
+    buf[:] = fill
+    pool.release(buf, recycle=True)
+    assert pool.parked_bytes() == nbytes
+    return pool
+
+
+def _source(n: int, seed: int = 0) -> bytes:
+    return np.random.default_rng(seed).integers(
+        0, 256, n, dtype=np.uint8).tobytes()
+
+
+MODES = {
+    # name: (content length, constructor keywords, (offset, size) of each
+    # array the sink hands over, in the order it hands them over)
+    "manifest": (1000, dict(shard_specs=[("a", 0, 300), ("b", 300, 500),
+                                          ("tail", 900, 100)]),
+                 [(0, 300), (300, 500), (900, 100)]),
+    "whole_buffer": (1001, dict(devices=[object(), object(), object()],
+                                dtype="uint16"),
+                     [(0, 334), (334, 334), (668, 334)]),
+    "shards_per_device": (1000, dict(devices=[object()],
+                                     shards_per_device=4),
+                          [(0, 250), (250, 250), (500, 250), (750, 250)]),
+}
+
+
+def _arrays(result) -> list:
+    return list(result.values()) if isinstance(result, dict) else result
+
+
+class TestStagingSplit:
+    @pytest.mark.parametrize("mode", sorted(MODES))
+    def test_copy_then_commit_from_threads_gives_what_write_gave(self, mode):
+        """The copy (any thread, disjoint ranges, no bookkeeping) and the
+        bookkeeping (afterwards) hand over the arrays ``write`` does."""
+        import threading
+
+        n, kw, _ranges = MODES[mode]
+        kw = dict({"devices": [object()]}, **kw)
+        raw = _source(n)
+        step = 97
+        offsets = list(range(0, n, step))
+
+        whole = DeviceIngest(n, device_put_fn=_copying_put,
+                             pool=SinkBufferPool(), **kw)
+        for off in offsets:
+            whole.write(off, raw[off:off + step])
+        want = _arrays(whole.result(timeout=10))
+
+        split = DeviceIngest(n, device_put_fn=_copying_put,
+                             pool=SinkBufferPool(), **kw)
+        leases = [split.lease() for _ in range(4)]
+        go = threading.Barrier(len(leases))
+
+        def stage(k: int) -> None:
+            go.wait(5)
+            for off in offsets[k::len(leases)]:
+                leases[k].copy(off, raw[off:off + step])
+
+        threads = [threading.Thread(target=stage, args=(k,))
+                   for k in range(len(leases))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(10)
+            assert not t.is_alive()
+        assert sum(ls.nbytes for ls in leases) == n
+        assert all(ls.error is None and ls.seconds > 0 for ls in leases)
+        # nothing was accounted by the copies: no shard has been enqueued
+        assert not any(split._shard_queued)
+        for ls in leases:
+            ls.release()
+        for off in offsets:
+            split.commit(off, len(raw[off:off + step]))
+        got = _arrays(split.result(timeout=10))
+        assert len(got) == len(want)
+        for a, b in zip(got, want):
+            assert a.dtype == b.dtype and np.array_equal(a, b)
+
+    def test_lease_keeps_errors_and_never_raises_into_the_landing(self):
+        di = DeviceIngest(100, devices=[object()],
+                          device_put_fn=_copying_put, pool=SinkBufferPool())
+        with di.lease() as lease:
+            assert lease.address(90, 20) == 0
+            assert isinstance(lease.error, ValueError)
+            lease.copy(0, b"x" * 10)          # a failed lease copies nothing
+            assert lease.nbytes == 0
+        with pytest.raises(ValueError, match="beyond content"):
+            di.write(95, b"y" * 10)
+        with pytest.raises(ValueError, match="beyond content"):
+            di.commit(95, 10)
+        di.close()
+
+    def test_a_replaced_write_is_handed_every_piece(self):
+        """A sink whose ``write`` is not DeviceIngest's own (a subclass, a
+        double, the benchmark's planted fault) sees every piece through it:
+        the lease offers native code no address to copy past it."""
+        seen = []
+
+        class Doubling(DeviceIngest):
+            def write(self, offset, data):
+                seen.append((offset, len(data)))
+                super().write(offset, bytes(b ^ 0xFF for b in data))
+
+        raw = _source(64)
+        di = Doubling(64, devices=[object()], device_put_fn=_copying_put,
+                      pool=SinkBufferPool())
+        with di.lease() as lease:
+            assert lease.address(0, 64) == 0 and lease.error is None
+            lease.copy(0, raw)
+            assert lease.nbytes == 64
+        di.commit(0, 64)                     # idempotent after write's own
+        (arr,) = di.result(timeout=10)
+        assert seen == [(0, 64)]
+        assert bytes(arr) == bytes(b ^ 0xFF for b in raw)
+
+
+class TestSinkBufferPool:
+    @pytest.mark.parametrize("mode", sorted(MODES))
+    def test_a_dirty_recycled_buffer_yields_the_sources_bytes(self, mode):
+        """A released buffer comes back full of another task's bytes (here
+        0xFF): what reaches the device is the source and, past the content,
+        zeros."""
+        n, kw, ranges = MODES[mode]
+        kw = dict({"devices": [object()]}, **kw)
+        raw = _source(n, seed=1)
+        pool = _dirty_pool(4096)
+        di = DeviceIngest(n, device_put_fn=_copying_put, pool=pool, **kw)
+        assert di.pool_hit and pool.parked_bytes() == 0
+        assert di.host.nbytes == di.padded_length <= 4096
+        for off in range(0, n, 128):
+            di.write(off, raw[off:off + 128])
+        got = _arrays(di.result(timeout=10))
+        padded = np.frombuffer(
+            raw + bytes(di.padded_length - n), dtype=np.uint8)
+        assert len(got) == len(ranges)
+        for arr, (off, size) in zip(got, ranges):
+            assert np.array_equal(arr.view(np.uint8).reshape(-1),
+                                  padded[off:off + size])
+        # every shard shipped: the whole 4096-byte buffer is parked again
+        di._worker.join(5)
+        assert di.host is None and pool.parked_bytes() == 4096
+
+    def test_a_smaller_lease_hits_a_larger_parked_buffer_best_fit(self):
+        pool = SinkBufferPool()
+        bufs = [pool.acquire(n)[0] for n in (1000, 3000, 2000)]
+        for b in bufs:
+            pool.release(b, recycle=True)
+        assert pool.parked_bytes() == 6000
+        got, hit = pool.acquire(1500)
+        assert hit and got.nbytes == 2000     # the smallest that fits
+        got2, hit2 = pool.acquire(2500)
+        assert hit2 and got2.nbytes == 3000
+        fresh, hit3 = pool.acquire(2500)      # only the 1000 is left
+        assert not hit3 and fresh.nbytes == 2500
+
+    def test_parked_bytes_are_bounded_by_what_was_leased_at_once(self):
+        """Parked + leased never pass the high-water mark of leased bytes:
+        the pool keeps what the sinks held a moment earlier, no more."""
+        pool = SinkBufferPool()
+        a, _ = pool.acquire(640)
+        pool.release(a, recycle=True)
+        assert pool.parked_bytes() == 640
+        b, hit = pool.acquire(1056)           # the 640 cannot serve it
+        assert not hit and pool.parked_bytes() == 0     # and had to go
+        pool.release(b, recycle=True)
+        assert pool.parked_bytes() == 1056
+        c, hit = pool.acquire(640)
+        assert hit and c is b
+        d, hit = pool.acquire(640)            # two at once: the mark rises
+        assert not hit
+        pool.release(c, recycle=True)
+        pool.release(d, recycle=True)
+        assert pool.parked_bytes() == 1056 + 640
+        e, _ = pool.acquire(100)
+        pool.release(e, recycle=False)        # an aliased buffer is dropped
+        assert pool.parked_bytes() == 1056
+
+    def test_not_parked_while_a_landing_holds_it(self):
+        """close() mid-landing (a lost sink) must neither free nor recycle
+        the buffer under the landing's pointer."""
+        pool = SinkBufferPool()
+        di = DeviceIngest(1000, devices=[object()],
+                          device_put_fn=_copying_put, pool=pool)
+        lease = di.lease()
+        addr = lease.address(0, 1000)
+        assert addr == di.host.ctypes.data
+        di.close()
+        di._worker.join(5)
+        assert not di._worker.is_alive()
+        assert di.host is not None and pool.parked_bytes() == 0
+        lease.copy(0, b"z" * 1000)            # the landing finishes its copy
+        assert lease.error is None and bytes(di.host) == b"z" * 1000
+        lease.release()
+        assert di.host is None and pool.parked_bytes() == 1000
+        lease.release()                       # idempotent
+        assert pool.parked_bytes() == 1000
+        # a landing that begins after the buffer has gone copies nowhere
+        late = di.lease()
+        late.copy(0, b"q" * 10)
+        assert late.nbytes == 0 and late.error is None and late.took(0)
+        late.release()
+
+    def test_not_parked_while_a_transfer_reads_it(self):
+        import threading
+
+        pool = SinkBufferPool()
+        reading, release = threading.Event(), threading.Event()
+
+        def held_put(view, device):
+            reading.set()
+            assert release.wait(10)
+            return np.array(view, copy=True)
+
+        di = DeviceIngest(100, devices=[object()], device_put_fn=held_put,
+                          pool=pool)
+        di.write(0, b"k" * 100)
+        assert reading.wait(5)
+        di.close()                            # the sentinel queues behind it
+        assert di.host is not None and pool.parked_bytes() == 0
+        release.set()
+        di.drain(timeout=10)
+        assert di.host is None and pool.parked_bytes() == 100
+
+    def test_a_failed_transfer_does_not_recycle(self):
+        pool = SinkBufferPool()
+
+        def bad_put(view, device):
+            raise RuntimeError("boom")
+
+        di = DeviceIngest(100, devices=[object()], device_put_fn=bad_put,
+                          pool=pool)
+        di.write(0, b"x" * 100)
+        with pytest.raises(RuntimeError):
+            di.result(timeout=10)
+        di._worker.join(5)
+        assert di.host is None and pool.parked_bytes() == 0
+
+    def test_nothing_is_recycled_under_a_live_array_on_the_cpu_backend(self):
+        """``jax.device_put`` on the CPU backend hands back a VIEW of a
+        64-byte-aligned source. Such a buffer must never reach the pool:
+        a second task through the same pool leaves the first one's arrays
+        as they were."""
+        import jax
+
+        dev = jax.devices()[0]
+        size, count = 4096, 64
+        # starts 4097 apart walk through every residue mod 64, so whatever
+        # the buffer's own alignment at least one range is 64-byte aligned
+        specs = [(f"s{k}", k * (size + 1), size) for k in range(count)]
+        n = count * (size + 1)
+        pool = SinkBufferPool()
+
+        def task(seed: int):
+            raw = _source(n, seed)
+            di = DeviceIngest(n, devices=[dev], shard_specs=specs, pool=pool)
+            di.write(0, raw)
+            out = di.result(timeout=30)
+            di._worker.join(5)
+            return raw, di, out
+
+        raw1, di1, first = task(1)
+        aliased = di1._host_aliased
+        if not aliased:
+            pytest.skip("this CPU backend copied every range")
+        assert pool.parked_bytes() == 0       # dropped, not parked
+        raw2, di2, second = task(2)
+        assert not di2.pool_hit
+        for name, off, sz in specs:
+            assert bytes(np.asarray(first[name])) == raw1[off:off + sz]
+            assert bytes(np.asarray(second[name])) == raw2[off:off + sz]
+
+    def test_the_default_pool_is_the_process_pool(self):
+        import jax
+
+        di = DeviceIngest(10, devices=[jax.devices()[0]])
+        assert di._pool is HOST_POOL
+        di.close()
+
+    def test_leases_and_pool_survive_many_threads(self):
+        """More threads than cores open sinks on one pool, stage, ship and
+        let go: no buffer is ever in two sinks at once, every array is its
+        own source, and the pool's books balance at the end."""
+        import sys
+        import threading
+
+        pool = SinkBufferPool()
+        in_use: set[int] = set()
+        guard = threading.Lock()
+        errors: list[BaseException] = []
+
+        def put(view, device):
+            return np.array(view, copy=True)
+
+        def worker(k: int) -> None:
+            try:
+                for r in range(12):
+                    n = 500 + 37 * ((k + r) % 5)
+                    raw = _source(n, seed=k * 100 + r)
+                    di = DeviceIngest(n, devices=[object()],
+                                      shards_per_device=2,
+                                      device_put_fn=put, pool=pool)
+                    key = di._backing.ctypes.data
+                    with guard:
+                        assert key not in in_use
+                        in_use.add(key)
+                    with di.lease() as lease:
+                        lease.copy(0, raw)
+                    assert lease.error is None
+                    with guard:       # before the last shard can ship
+                        in_use.discard(key)
+                    di.commit(0, n)
+                    out = np.concatenate(di.result(timeout=20))
+                    assert bytes(out[:n]) == raw
+            except BaseException as exc:  # noqa: BLE001 - reported below
+                errors.append(exc)
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=worker, args=(k,))
+                       for k in range(24)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+                assert not t.is_alive()
+        finally:
+            sys.setswitchinterval(old)
+        assert not errors, errors[0]
+        assert pool._leased_bytes == 0
+        assert pool.parked_bytes() == sum(b.nbytes for b in pool._parked)
+        assert pool.parked_bytes() <= pool._leased_peak

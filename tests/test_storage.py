@@ -206,3 +206,205 @@ class TestNativePieceIO:
                            piece_digest="crc32c:00000000")
         assert 0 not in ts.md.pieces
         assert not ts.has_range(0, 100)
+
+
+# ----------------------------------------------------------------------
+# landings that stage into a device sink (``stage=``: the sink's lease)
+# ----------------------------------------------------------------------
+
+PIECE = 64 * 1024
+KEEP = 0xAA                                  # what the sink's buffer held
+
+
+def _sink(n: int):
+    """A device sink whose host buffer is ``KEEP`` all over, so a byte a
+    landing did not stage is seen to be as it was."""
+    import numpy as np
+
+    from dragonfly2_tpu.tpu.hbm_sink import DeviceIngest, SinkBufferPool
+
+    di = DeviceIngest(n, devices=[object()], pool=SinkBufferPool(),
+                      device_put_fn=lambda v, d: np.array(v, copy=True))
+    di.host[:] = KEEP
+    return di
+
+
+def _span_spec(blob: bytes, algo: str = ""):
+    algo = algo or digestlib.preferred_piece_algo()
+    return [(i, off, len(blob[off:off + PIECE]),
+             digestlib.for_bytes(algo, blob[off:off + PIECE]))
+            for i, off in enumerate(range(0, len(blob), PIECE))]
+
+
+def _force(monkeypatch, path: str) -> None:
+    """Which traversal ``write_span`` takes: the fused native call that
+    also stages; a library built before that export (native landing, the
+    copy in Python); no library at all."""
+    from dragonfly2_tpu.storage import native
+
+    if path == "native":
+        if not (native.available() and getattr(
+                native.load(), "_df_has_span_stage", False)):
+            pytest.skip("native lib not built")
+        return
+    monkeypatch.setattr(native, "span_write_staged", lambda *a, **k: None)
+    if path == "python":
+        monkeypatch.setattr(native, "span_write", lambda *a, **k: None)
+    elif not native.available():
+        pytest.skip("native lib not built")
+
+
+PATHS = ("native", "stale_so", "python")
+
+
+class TestStagedLanding:
+    def _storage(self, tmp_path, name="st"):
+        from dragonfly2_tpu.storage.store import TaskStorage
+        return TaskStorage(str(tmp_path / name), TaskMetadata(
+            task_id=name * 32, url="test://staged"))
+
+    @pytest.mark.parametrize("path", PATHS)
+    def test_verified_pieces_reach_the_sink_a_corrupt_one_never(
+            self, tmp_path, monkeypatch, path):
+        _force(monkeypatch, path)
+        blob = os.urandom(4 * PIECE + 321)
+        spec = _span_spec(blob)
+        wire = bytearray(blob)
+        wire[PIECE + 9] ^= 0x40              # piece 1 arrives corrupt
+        di = _sink(len(blob))
+        ts = self._storage(tmp_path)
+        with di.lease() as lease:
+            metas, corrupt, took = ts.write_span(spec, bytes(wire),
+                                                 stage=lease)
+            assert lease.error is None
+            assert lease.nbytes == len(blob) - PIECE and lease.seconds > 0
+        assert corrupt == [1] and [m.num for m in metas] == [0, 2, 3, 4]
+        assert took == ("python" if path == "python" else "native")
+        host = bytes(di.host)
+        assert host[:PIECE] == blob[:PIECE]
+        assert host[PIECE:2 * PIECE] == bytes([KEEP]) * PIECE   # untouched
+        assert host[2 * PIECE:] == blob[2 * PIECE:]
+        # the retry's good copy is staged like any other piece
+        with di.lease() as lease:
+            metas, corrupt, _ = ts.write_span(
+                [spec[1]], blob[PIECE:2 * PIECE], base=PIECE, stage=lease)
+            assert lease.nbytes == PIECE
+        assert [m.num for m in metas] == [1] and not corrupt
+        assert bytes(di.host) == blob
+        ts.close()
+        di.close()
+
+    def test_native_and_python_stage_the_same_bytes(self, tmp_path,
+                                                    monkeypatch):
+        from dragonfly2_tpu.storage import native
+        if not (native.available() and getattr(
+                native.load(), "_df_has_span_stage", False)):
+            pytest.skip("native lib not built")
+        blob = os.urandom(5 * PIECE + 77)
+        spec = _span_spec(blob)
+        spec[3] = spec[3][:3] + ("",)        # a piece that carries no digest
+        wire = bytearray(blob)
+        wire[4 * PIECE + 1] ^= 1             # and a corrupt one
+        hosts, recorded = [], []
+        for path in PATHS:
+            with monkeypatch.context() as mp:
+                _force(mp, path)
+                di = _sink(len(blob))
+                ts = self._storage(tmp_path, path[0])
+                with di.lease() as lease:
+                    metas, corrupt, _ = ts.write_span(spec, bytes(wire),
+                                                      stage=lease)
+                hosts.append(bytes(di.host))
+                recorded.append(([(m.num, m.digest) for m in metas], corrupt,
+                                 lease.nbytes))
+                ts.close()
+                di.close()
+        assert hosts[0] == hosts[1] == hosts[2]
+        assert recorded[0] == recorded[1] == recorded[2]
+        assert recorded[0][1] == [4]
+
+    @pytest.mark.parametrize("path", PATHS)
+    def test_an_already_recorded_piece_is_not_copied_again(
+            self, tmp_path, monkeypatch, path):
+        """An endgame duplicate's span carries unverified bytes of a piece
+        that is already recorded: skipped on disk, and skipped in the
+        sink."""
+        _force(monkeypatch, path)
+        blob = os.urandom(3 * PIECE)
+        spec = _span_spec(blob)
+        di = _sink(len(blob))
+        ts = self._storage(tmp_path)
+        ts.write_piece(1, PIECE, blob[PIECE:2 * PIECE], spec[1][3])
+        racer = bytearray(blob)
+        racer[PIECE + 3] ^= 0xFF
+        with di.lease() as lease:
+            metas, corrupt, _ = ts.write_span(spec, bytes(racer),
+                                              stage=lease)
+            assert lease.nbytes == 2 * PIECE
+        assert [m.num for m in metas] == [0, 2] and not corrupt
+        host = bytes(di.host)
+        assert host[:PIECE] == blob[:PIECE]
+        assert host[PIECE:2 * PIECE] == bytes([KEEP]) * PIECE
+        assert host[2 * PIECE:] == blob[2 * PIECE:]
+        ts.close()
+        di.close()
+
+    def test_a_digest_the_crc_path_cannot_check_is_staged_once_verified(
+            self, tmp_path):
+        """sha256 piece digests take the Python traversal whatever is
+        built; the copy still follows the verdict."""
+        blob = os.urandom(2 * PIECE)
+        spec = _span_spec(blob, "sha256")
+        wire = bytearray(blob)
+        wire[5] ^= 2
+        di = _sink(len(blob))
+        ts = self._storage(tmp_path)
+        with di.lease() as lease:
+            metas, corrupt, took = ts.write_span(spec, bytes(wire),
+                                                 stage=lease)
+        assert took == "python" and corrupt == [0]
+        assert bytes(di.host) == bytes([KEEP]) * PIECE + blob[PIECE:]
+        ts.close()
+        di.close()
+
+    def test_a_range_beyond_the_sink_fails_the_lease_not_the_landing(
+            self, tmp_path):
+        blob = os.urandom(2 * PIECE)
+        di = _sink(PIECE)                    # a sink too short for the span
+        ts = self._storage(tmp_path)
+        with di.lease() as lease:
+            metas, corrupt, _ = ts.write_span(_span_spec(blob), blob,
+                                              stage=lease)
+            assert isinstance(lease.error, ValueError) and not lease.nbytes
+        assert [m.num for m in metas] == [0, 1] and not corrupt
+        assert ts.read_piece(1) == blob[PIECE:]          # on disk all the same
+        assert bytes(di.host) == bytes([KEEP]) * PIECE
+        ts.close()
+        di.close()
+
+    @pytest.mark.parametrize("kind", ["task", "subtask"])
+    def test_write_piece_stages_after_the_verdict(self, tmp_path, kind):
+        blob = os.urandom(2 * PIECE)
+        spec = _span_spec(blob)
+        if kind == "task":
+            ts = self._storage(tmp_path)
+        else:
+            ts = make_manager(tmp_path).register_subtask(TaskMetadata(
+                task_id="s" * 64, parent_task_id="p" * 64, range_start=512,
+                range_length=len(blob), content_length=len(blob)))
+        di = _sink(len(blob))
+        bad = bytearray(blob[:PIECE])
+        bad[0] ^= 1
+        with di.lease() as lease:
+            with pytest.raises(DFError) as ei:
+                ts.write_piece(0, 0, bytes(bad), spec[0][3], stage=lease)
+            assert ei.value.code == Code.CLIENT_DIGEST_MISMATCH
+            assert lease.nbytes == 0
+            ts.write_piece(0, 0, blob[:PIECE], spec[0][3], stage=lease)
+            assert lease.nbytes == PIECE
+            # recorded already: returned as it is, and copied nowhere
+            ts.write_piece(0, 0, bytes(bad), "", stage=lease)
+            assert lease.nbytes == PIECE and not lease.took(PIECE)
+            ts.write_piece(1, PIECE, blob[PIECE:], spec[1][3], stage=lease)
+        assert bytes(di.host) == blob
+        di.close()

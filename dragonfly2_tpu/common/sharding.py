@@ -51,9 +51,11 @@ def parse_shard_names(csv: str) -> list[str]:
 
 def validate_manifest(shards: Sequence, content_length: int = -1) -> None:
     """Raise ValueError on a malformed manifest: empty/duplicate names,
-    non-positive sizes, overlapping ranges, or ranges beyond the content
-    (when its length is known). Gaps are LEGAL — a manifest may name only
-    the tensors worth landing (optimizer state can stay unnamed)."""
+    non-positive sizes, overlapping ranges, ranges beyond the content
+    (when its length is known), or a placement under -1 (unplaced; whether
+    the holder HAS the chip a shard names is the sink's to say). Gaps are
+    LEGAL — a manifest may name only the tensors worth landing (optimizer
+    state can stay unnamed)."""
     seen: set[str] = set()
     spans: list[tuple[int, int, str]] = []
     for s in shards:
@@ -66,6 +68,9 @@ def validate_manifest(shards: Sequence, content_length: int = -1) -> None:
             raise ValueError(f"shard {s.name}: non-positive size")
         if s.range_start < 0:
             raise ValueError(f"shard {s.name}: negative start")
+        if getattr(s, "device", -1) < -1:
+            raise ValueError(f"shard {s.name}: device {s.device} is neither "
+                             "a chip's ordinal nor -1 (unplaced)")
         if content_length >= 0 and s.range_start + s.range_size > content_length:
             raise ValueError(
                 f"shard {s.name}: [{s.range_start}, "

@@ -220,9 +220,26 @@ class PeerTaskConductor:
                           peer_id=self.peer_id[-16:], url=self.url) as sp:
             await self._run_traced(sp)
 
+    def _refuse_unplaceable_manifest(self) -> None:
+        """A manifest that places a shard on a chip this host's sink is
+        not opened over can never be honoured: the task fails here, at
+        open, before register and before a byte moves (the sink itself
+        would refuse it only once the content length is known)."""
+        chips = getattr(self.device_sink_factory, "chips", None)
+        if chips is None or not self.shard_manifest:
+            return
+        worst = max(self.shard_manifest, key=lambda s: s.device)
+        if worst.device >= chips:
+            self.sink_error = (
+                f"device sink refused: shard {worst.name} is placed on "
+                f"device {worst.device}, this host's sink is open over "
+                f"{chips}")
+            raise DFError(Code.CLIENT_DEVICE_SINK_ERROR, self.sink_error)
+
     async def _run_traced(self, sp) -> None:
         try:
             used_p2p = False
+            self._refuse_unplaceable_manifest()
             if await self._try_adopt_content():
                 # the whole task's bytes were already on disk under another
                 # task id (content-digest hit): placed, not transferred —
@@ -743,7 +760,8 @@ class PeerTaskConductor:
         if tracker is None:
             return None
         return [(s.name, s.range_start, s.range_size, s.dtype,
-                 list(s.shape) if s.shape else None)
+                 list(s.shape) if s.shape else None,
+                 s.device)
                 for s in tracker.shards]
 
     def _make_device_ingest(self, content_length: int):
@@ -1213,12 +1231,13 @@ class PeerTaskConductor:
         # task's trace (schedule decision -> piece fetch -> HBM)
         from ..common import tracing
         spans = list(ingest.transfer_spans)
+        chips = list(ingest.transfer_chips)    # drained: one a span
         with tracing.span("hbm.ingest", task_id=self.task_id[:16]) as hsp:
             hsp.set(transfers=len(spans),
                     done_fraction=ingest.done_fraction(),
                     dma_ms=round(sum(b - a for a, b in spans) * 1e3, 3))
         if self.flight is not None:
-            self.flight.hbm_spans(spans)
+            self.flight.hbm_spans(spans, chips)
 
     async def _finish_fail(self, code: Code, message: str) -> None:
         if self.state in (self.SUCCESS, self.FAILED):

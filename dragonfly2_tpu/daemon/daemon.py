@@ -197,7 +197,9 @@ class Daemon:
         bring a device runtime up. ``shard_specs`` (sharded tasks,
         common/sharding.py) switches the sink to manifest mode: named
         uneven shards that each become a device array the moment their
-        bytes are covered."""
+        bytes are covered. ``factory.chips`` is how many chips the sink
+        is opened over: a manifest that places a shard beyond them is
+        refused by the conductor before a byte moves."""
         devices = await self.device_runtime()
 
         def factory(content_length: int, shard_specs: list | None = None):
@@ -217,6 +219,7 @@ class Daemon:
                 spd = max(1, min(32, per_dev // INGEST_DMA_UNIT_BYTES))
             return DeviceIngest(content_length, dtype=spec.dtype,
                                 devices=devices, shards_per_device=spd)
+        factory.chips = len(devices)
         return factory
 
     async def _enroll_security(self):

@@ -126,7 +126,8 @@ SHARD_FALLBACK = "shard_fallback"  # a swap-class piece (a shard assigned
 # from wedging on it — the sharded analog of a degradation-ladder rung
 # task-level stages
 REGISTERED = "registered"    # scheduler register returned
-HBM_SHARD = "hbm_shard"      # one device DMA completed (piece = shard idx)
+HBM_SHARD = "hbm_shard"      # one device DMA completed (piece = shard idx,
+# parent = the ordinal of the chip it went to, bytes = the shard's)
 DONE = "done"                # task reached a terminal state
 RUNG = "rung"                # degradation-ladder transition (parent = rung)
 QOS = "qos"                  # QoS admission ruling (parent = governor
@@ -258,12 +259,15 @@ class TaskFlight:
                             serve_ms, wait_ms, pieces, relayed))
         _serve_rows.inc()
 
-    def hbm_spans(self, spans: list) -> None:
+    def hbm_spans(self, spans: list, chips: list | None = None) -> None:
         """Adopt a DeviceIngest's completed transfer spans ((monotonic
-        start, end) pairs) as shard-level events on this flight's clock."""
+        start, end) pairs) as shard-level events on this flight's clock.
+        ``chips``, beside them: (ordinal of the chip, bytes) of each
+        transfer, journaled as the event's parent and bytes."""
         for idx, (t0, t1) in enumerate(spans):
+            chip, nbytes = chips[idx] if chips else (ORIGIN, 0)
             self.events.append((self.ms_at(t0), HBM_SHARD, idx,
-                                ORIGIN, 0, (t1 - t0) * 1000.0))
+                                str(chip), nbytes, (t1 - t0) * 1000.0))
 
     # -- consumption ---------------------------------------------------
 

@@ -266,6 +266,10 @@ class ShardInfo:
     dtype: str = "uint8"             # numpy dtype string for the array view
     shape: list[int] | None = None   # array shape; None = flat bytes
     digest: str = ""                 # optional "sha256:..." of the shard
+    # the chip this array is to be on: its ordinal among the chips the
+    # holder's sink is opened over (``Daemon.device_runtime()``'s list, in
+    # its order); -1 is unplaced (the sink spreads such shards round-robin)
+    device: int = -1
 
 
 @message

@@ -52,7 +52,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--shard-manifest", default="", dest="shard_manifest",
                    help="path to a shard-manifest JSON file ({\"shards\": "
                    "[{name, range_start, range_size, dtype?, shape?, "
-                   "digest?}, ...]}); per-shard ready timestamps are "
+                   "digest?, device?}, ...]}); per-shard ready timestamps are "
                    "printed as shards verify")
     p.add_argument("--header", action="append", default=[],
                    help="extra origin header K:V (repeatable)")
@@ -103,7 +103,8 @@ def _load_shard_manifest(path: str):
                         range_size=int(e["range_size"]),
                         dtype=e.get("dtype", "uint8"),
                         shape=list(e["shape"]) if e.get("shape") else None,
-                        digest=e.get("digest", ""))
+                        digest=e.get("digest", ""),
+                        device=int(e.get("device", -1)))
               for e in entries]
     return ShardManifest(shards=shards)
 

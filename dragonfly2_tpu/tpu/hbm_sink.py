@@ -13,12 +13,15 @@ in GPU-side distribution stacks (see BASELINE.json north star). Design:
   file's worth of fresh ones in.
 - The content is split into ``shard_count`` contiguous byte shards. The
   moment every byte of a shard is present, that shard's index is enqueued to
-  a dedicated transfer thread that owns every ``jax.device_put`` call.
-  ``write()`` never waits on a device transfer — on real TPU hardware
-  ``device_put`` of an unpinned host buffer is synchronous (it blocks the
-  caller for the whole staging copy + DMA), so dispatching it from the
-  asyncio event loop or awaiting it from the piece-landing path stalls the
-  daemon's own sockets. The worker thread absorbs that blocking.
+  the transfer thread of the chip it goes to: one queue and one thread per
+  device the task's shards reach, each owning every ``jax.device_put`` onto
+  its chip, so that the chips' DMAs run at once and a slow chip holds up
+  only its own queue. ``write()`` never waits on a device transfer — on
+  real TPU hardware ``device_put`` of an unpinned host buffer is
+  synchronous (it blocks the caller for the whole staging copy + DMA), so
+  dispatching it from the asyncio event loop or awaiting it from the
+  piece-landing path stalls the daemon's own sockets. The worker threads
+  absorb that blocking.
 - ``result()`` drains the transfer queue, blocks until the DMAs finish, and
   assembles per-device shards into ONE logically-global jax.Array via
   ``jax.make_array_from_single_device_arrays`` when a mesh sharding is
@@ -284,10 +287,10 @@ class CoverageMap:
 class DeviceIngest:
     """Streams a task's bytes into per-device shards as pieces arrive.
 
-    All device transfers run on one dedicated worker thread so neither the
-    asyncio event loop nor the piece-landing path ever blocks on DMA
-    (the round-3 TPU failure mode: ``device_put`` on-loop starved the
-    daemon's sockets mid-download).
+    All device transfers run on dedicated worker threads, one per chip
+    the task's shards go to, so neither the asyncio event loop nor the
+    piece-landing path ever blocks on DMA (the round-3 TPU failure mode:
+    ``device_put`` on-loop starved the daemon's sockets mid-download).
     """
 
     def __init__(self, content_length: int, *, devices: Any = None,
@@ -310,14 +313,18 @@ class DeviceIngest:
 
         ``shard_specs`` switches the sink to MANIFEST mode (sharded tasks,
         common/sharding.py): instead of equal-split anonymous shards, each
-        entry is ``(name, start, size[, dtype, shape])`` — a named byte
-        range that transfers the moment its bytes are covered (ranges may
-        be uneven, need not cover the content, and gaps never transfer).
-        ``result()`` then returns ``{name: array}``, each array viewed as
-        the spec's dtype (the sink default when "") and reshaped to the
-        spec's shape when one is given. Devices are assigned round-robin
-        per spec. Incompatible with ``sharding`` (global-array assembly
-        needs the equal-split geometry). ``on_shard_ready`` is called ON
+        entry is ``(name, start, size[, dtype, shape, device])`` — a named
+        byte range that transfers the moment its bytes are covered (ranges
+        may be uneven, need not cover the content, and gaps never
+        transfer). ``result()`` then returns ``{name: array}``, each array
+        viewed as the spec's dtype (the sink default when "") and reshaped
+        to the spec's shape when one is given. ``device`` is the ordinal in
+        ``devices`` of the chip the array is to be on (``ShardInfo.device``:
+        the manifest says, the sink obeys); -1 or absent is unplaced, and
+        an unplaced spec goes round-robin by its index. An ordinal the sink
+        has no device for is refused here. Incompatible with ``sharding``
+        (global-array assembly needs the equal-split geometry).
+        ``on_shard_ready`` is called ON
         THE TRANSFER THREAD as ``(name, monotonic_done_time)`` after each
         named shard's device transfer completes — callbacks must be cheap
         and thread-safe (hand off to the loop, don't compute). ``pool``:
@@ -358,9 +365,18 @@ class DeviceIngest:
                 if size % sdtype.itemsize:
                     raise ValueError(f"shard {name}: size {size} not a "
                                      f"multiple of {sdtype} itemsize")
-                specs.append((name, start, size, sdtype, shape))
+                device = int(sp[5]) if len(sp) > 5 and sp[5] is not None \
+                    else -1
+                if not -1 <= device < len(self.devices):
+                    raise ValueError(
+                        f"shard {name}: placed on device {device}, the "
+                        f"sink is open over {len(self.devices)}")
+                specs.append((name, start, size, sdtype, shape, device))
             self._specs = specs
             n = len(specs)
+            self._shard_device = [sp[5] if sp[5] >= 0
+                                  else i % len(self.devices)
+                                  for i, sp in enumerate(specs)]
             self.n_shards = n
             self.padded_length = content_length
             self.shard_bytes = 0            # uneven; see _shard_range
@@ -375,6 +391,8 @@ class DeviceIngest:
             padded = -(-content_length // (n * itemsize)) * (n * itemsize)
             self.padded_length = padded
             self.shard_bytes = padded // n
+            self._shard_device = [i // self.shards_per_device
+                                  for i in range(n)]
         self._coverage = CoverageMap()
         self._shard_arrays: list[Any | None] = [None] * n
         self._shard_sent = [False] * n       # transfer COMPLETED
@@ -383,9 +401,14 @@ class DeviceIngest:
         # callers measure how much DMA ran concurrently with the download
         # without run-to-run wall-clock subtraction (bench + tracing)
         self.transfer_spans: list[tuple[float, float]] = []
+        # beside each span: (ordinal of the chip it went to, bytes)
+        self.transfer_chips: list[tuple[int, int]] = []
         self._lock = threading.Lock()
         self._device_put = device_put_fn or jax.device_put
-        self._queue: queue.SimpleQueue = queue.SimpleQueue()
+        # one queue and one worker per chip that a shard of this task goes
+        # to (one, on a one-chip host)
+        self._queues: dict[int, queue.SimpleQueue] = {
+            d: queue.SimpleQueue() for d in sorted(set(self._shard_device))}
         self._pending = 0                    # queued-but-unfinished transfers
         self._idle = threading.Event()
         self._idle.set()
@@ -400,11 +423,14 @@ class DeviceIngest:
         self.host: np.ndarray | None = self._backing[:self.padded_length]
         self.host[content_length:] = 0
         self._leases = 0                     # StageLeases out
-        self._worker_done = False
+        self._workers_live = len(self._queues)
+        self._workers_done = False           # no worker can touch the buffer
         self._host_aliased = False           # a device array reads it in place
-        self._worker = threading.Thread(target=self._transfer_loop,
-                                        name="hbm-sink", daemon=True)
-        self._worker.start()
+        self._workers = [threading.Thread(
+            target=self._transfer_loop, args=(q,), name=f"hbm-sink-{d}",
+            daemon=True) for d, q in self._queues.items()]
+        for w in self._workers:
+            w.start()
         if content_length < self.padded_length:  # pad tail trivially "present"
             self._coverage.add(content_length, self.padded_length)
         log.info("device sink open: %d bytes -> %d shards on %d %s device(s) "
@@ -432,12 +458,12 @@ class DeviceIngest:
 
     def _release_host(self) -> None:
         """Let the host buffer go once no reader or writer of it can
-        exist: the transfer worker has exited (every shard's
-        ``device_put`` has returned from ``block_until_ready``, or the
-        sink was closed and the transfers queued before that are through)
-        and no landing holds a lease. It is recycled unless a device array
-        reads it in place. Called under ``_lock``."""
-        if (self._worker_done and not self._leases
+        exist: every shard's ``device_put`` has returned from
+        ``block_until_ready``, or every transfer worker has exited (the
+        sink was closed and the transfers queued before that are through,
+        on every chip), and no landing holds a lease. It is recycled
+        unless a device array reads it in place. Called under ``_lock``."""
+        if (self._workers_done and not self._leases
                 and self._backing is not None):
             backing, self._backing, self.host = self._backing, None, None
             self._pool.release(
@@ -491,7 +517,7 @@ class DeviceIngest:
 
     def _shard_range(self, shard: int) -> tuple[int, int]:
         if self._specs is not None:
-            _name, s, size, _dt, _shape = self._specs[shard]
+            s, size = self._specs[shard][1:3]
             return s, s + size
         return shard * self.shard_bytes, (shard + 1) * self.shard_bytes
 
@@ -512,7 +538,7 @@ class DeviceIngest:
             # it, a concurrent close() could slip its sentinel in first and
             # the worker would exit with this shard queued behind it, leaving
             # _pending stuck > 0 and drain() hung
-            self._queue.put(shard)
+            self._queues[self._shard_device[shard]].put(shard)
 
     def flush(self) -> None:
         """Enqueue any fully-covered shard whose transfer hasn't fired — in
@@ -523,20 +549,29 @@ class DeviceIngest:
             self._maybe_enqueue(shard)
 
     # ------------------------------------------------------------------
-    # worker thread — owns every device_put
+    # worker threads — each owns every device_put onto its chip
     # ------------------------------------------------------------------
 
-    def _transfer_loop(self) -> None:
+    def _transfer_loop(self, q: queue.SimpleQueue) -> None:
         try:
             while True:
-                shard = self._queue.get()
+                shard = q.get()
                 # None: shutdown sentinel
                 if shard is None or self._transfer(shard):
                     return
         finally:
             with self._lock:
-                self._worker_done = True
-                self._release_host()
+                self._workers_live -= 1
+                if not self._workers_live:
+                    self._workers_done = True
+                    self._release_host()
+
+    def _stop_workers(self) -> None:
+        """A sentinel behind whatever each chip's queue still holds.
+        Called under ``_lock``, once."""
+        self._closed = True
+        for q in self._queues.values():
+            q.put(None)
 
     def _reads_host_in_place(self, arr: Any, device: Any) -> bool:
         """Whether a transferred array is a view of the host buffer and
@@ -563,15 +598,15 @@ class DeviceIngest:
         try:
             s, e = self._shard_range(shard)
             if self._specs is not None:
-                name, _s, _size, sdtype, shape = self._specs[shard]
+                name, _s, _size, sdtype, shape, _dev = self._specs[shard]
                 view = self.host[s:e].view(sdtype)
                 if shape is not None:
                     view = view.reshape(shape)
-                device = self.devices[shard % len(self.devices)]
             else:
                 name = None
                 view = self.host[s:e].view(self.dtype)
-                device = self.devices[shard // self.shards_per_device]
+            chip = self._shard_device[shard]
+            device = self.devices[chip]
             t0 = time.monotonic()
             with tracing.annotate("hbm_transfer"):
                 arr = self._device_put(view, device)
@@ -589,6 +624,7 @@ class DeviceIngest:
                 self._shard_arrays[shard] = arr
                 self._shard_sent[shard] = True
                 self.transfer_spans.append((t0, t1))
+                self.transfer_chips.append((chip, e - s))
             _hbm_transfer_s.observe(t1 - t0)
             _hbm_transfers.labels("ok").inc()
             if name is not None and self.on_shard_ready is not None:
@@ -609,14 +645,17 @@ class DeviceIngest:
                 _hbm_queue.dec()
                 # self-terminate once every shard has shipped: a consumer
                 # that never calls result()/close() (task finished, nobody
-                # collected) must not leak this thread, nor keep the
-                # file-sized host buffer out of the pool. The buffer goes
-                # before drain() is woken, so that the next task's sink
-                # finds it parked
+                # collected) must not leak these threads, nor keep the
+                # file-sized host buffer out of the pool. The last transfer
+                # of the task is this one, so the other chips' workers are
+                # parked on empty queues: the buffer goes now, before
+                # drain() is woken, so that the next task's sink finds it
+                # parked
                 done = all(self._shard_sent)
                 if done:
-                    self._closed = True
-                    self._worker_done = True
+                    if not self._closed:
+                        self._stop_workers()
+                    self._workers_done = True
                     self._release_host()
                 if self._pending == 0:
                     self._idle.set()
@@ -640,13 +679,11 @@ class DeviceIngest:
                 raise RuntimeError("device transfer failed") from self._error
 
     def close(self) -> None:
-        """Stop the worker thread. Idempotent; safe mid-stream (pending
-        transfers finish first — the sentinel queues behind them)."""
+        """Stop the worker threads. Idempotent; safe mid-stream (pending
+        transfers finish first — the sentinels queue behind them)."""
         with self._lock:
-            if self._closed:
-                return
-            self._closed = True
-            self._queue.put(None)
+            if not self._closed:
+                self._stop_workers()
 
     def result(self, timeout: float | None = None):
         """Flush + drain, then return the device-resident data.
@@ -670,8 +707,8 @@ class DeviceIngest:
                            else i for i, s in enumerate(sent) if not s]
                 raise RuntimeError(f"shards incomplete: {missing}")
         finally:
-            # stop the worker on EVERY exit — a raising result() must not
-            # leave the thread parked on queue.get holding the host buffer
+            # stop the workers on EVERY exit — a raising result() must not
+            # leave a thread parked on queue.get holding the host buffer
             self.close()
         for a in arrays:
             # (an injected device_put_fn may hand back plain numpy)

@@ -231,6 +231,83 @@ class TestSinkIsNeverLostInSilence:
 
         _daemon_with_origin(tmp_path, {"w.bin": os.urandom(300_000)}, body)
 
+    def test_a_placed_manifest_lands_on_the_named_devices(self, tmp_path):
+        """Through ``start_file_task``: every array is on the chip its
+        manifest names (three of a kind together, as an expert's
+        matrices), the unplaced one where it went before, and the flight
+        journal's ``hbm_shard`` events name the chips and the bytes."""
+        import jax
+
+        from dragonfly2_tpu.idl.messages import ShardInfo, ShardManifest
+
+        data = os.urandom(13 * 40_000)
+        place = [3, 3, 3, 0, 0, 0, 2, 2, 2, 1, 1, 1, -1]
+        manifest = ShardManifest(shards=[ShardInfo(
+            name=f"t{i}", range_start=i * 40_000, range_size=40_000,
+            dtype="uint16", shape=[100, 200], device=d)
+            for i, d in enumerate(place)])
+
+        async def body(daemon, base):
+            url = f"{base}/w.bin"
+            async for _ in daemon.ptm.start_file_task(DownloadRequest(
+                    url=url, device_sink=DeviceSink(enabled=True),
+                    shard_manifest=manifest)):
+                pass
+            task_id = daemon.ptm._task_id(url, UrlMeta())
+            arrays = daemon.ptm.conductor(task_id).device_ingest.result()
+            devices = jax.local_devices()
+            for i, d in enumerate(place):
+                arr = arrays[f"t{i}"]
+                want = devices[d if d >= 0 else i % len(devices)]
+                assert arr.devices() == {want} and arr.shape == (100, 200)
+                assert np.asarray(arr).tobytes() == \
+                    data[i * 40_000:(i + 1) * 40_000]
+            events = [e for e in daemon.flight_recorder.get(task_id).events
+                      if e[1] == "hbm_shard"]
+            assert len(events) == 13
+            assert sorted(e[3] for e in events) == sorted(
+                str(d if d >= 0 else 12 % len(devices)) for d in place)
+            assert all(e[4] == 40_000 for e in events)
+
+        _daemon_with_origin(tmp_path, {"w.bin": data}, body)
+
+    def test_a_manifest_naming_a_missing_chip_fails_before_any_piece(
+            self, tmp_path):
+        """The host's sink is open over 8 devices here; a shard placed on a
+        ninth fails the request at open, typed, with the reason in the
+        terminal frame and the flight summary, and nothing was registered,
+        dispatched or stored."""
+        from dragonfly2_tpu.idl.messages import ShardInfo, ShardManifest
+
+        async def body(daemon, base):
+            url = f"{base}/w.bin"
+            with pytest.raises(DFError) as ei:
+                async for _ in daemon.ptm.start_file_task(DownloadRequest(
+                        url=url, device_sink=DeviceSink(enabled=True),
+                        shard_manifest=ShardManifest(shards=[
+                            ShardInfo(name="a", range_start=0,
+                                      range_size=1000, device=0),
+                            ShardInfo(name="b", range_start=1000,
+                                      range_size=1000, device=8)]))):
+                    pass
+            assert ei.value.code == Code.CLIENT_DEVICE_SINK_ERROR
+            assert "shard b is placed on device 8" in ei.value.message
+            assert "open over 8" in ei.value.message
+            task_id = daemon.ptm._task_id(url, UrlMeta())
+            conductor = daemon.ptm.conductor(task_id)
+            assert conductor.state == conductor.FAILED
+            assert conductor.storage is None and not conductor.ready
+            flight = daemon.flight_recorder.get(task_id)
+            summary = flight.summarize()
+            assert summary["state"] == "failed"
+            assert "placed on device 8" in summary["fail_reason"]
+            stages = {e[1] for e in flight.events}
+            assert not stages & {"registered", "scheduled", "dispatched",
+                                 "wire_done", "sink_open"}
+            assert not list(daemon.ptm.storage_mgr.tasks())
+
+        _daemon_with_origin(tmp_path, {"w.bin": os.urandom(100_000)}, body)
+
     def test_no_device_runtime_fails_before_any_byte_moves(
             self, tmp_path, monkeypatch):
         def no_backend():

@@ -530,9 +530,10 @@ class TestStagingInTheLanding:
             await asyncio.to_thread(entered.wait, 10)
             conductor._sink_lost("lost in the test")
             assert conductor.device_ingest is None
-            await asyncio.to_thread(sink._worker.join, 5)
-            # closed, its worker gone, and the buffer still the landing's
-            assert not sink._worker.is_alive()
+            for w in sink._workers:
+                await asyncio.to_thread(w.join, 5)
+            # closed, its workers gone, and the buffer still the landing's
+            assert not any(w.is_alive() for w in sink._workers)
             assert sink.host is not None and pool.parked_bytes() == 0
             gate.set()
             placed, corrupt, raced = await landing
@@ -564,7 +565,8 @@ class TestStagingInTheLanding:
                 placed, corrupt, _ = await conductor.on_span_from_peer(
                     "parent", self._infos(blob), blob, 1)
                 assert sorted(placed) == [0, 1, 2] and not corrupt
-                await asyncio.to_thread(sink._worker.join, 5)
+                for w in sink._workers:
+                    await asyncio.to_thread(w.join, 5)
 
             asyncio.run(go())
         finally:

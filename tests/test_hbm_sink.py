@@ -1,5 +1,7 @@
 """Stage-2 tests: coverage map + device ingest onto the 8-device CPU mesh."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -233,8 +235,9 @@ class TestDeviceIngest:
         ingest.write(0, b"x" * 100)
         with pytest.raises(RuntimeError):
             ingest.result(timeout=10)
-        ingest._worker.join(5)   # raising result() must still stop the worker
-        assert not ingest._worker.is_alive()
+        for w in ingest._workers:   # raising result() must still stop them
+            w.join(5)
+        assert not any(w.is_alive() for w in ingest._workers)
 
     def test_training_steps_while_ingest_streams(self):
         """BASELINE config #4's overlap claim at test scale: a jitted train
@@ -298,8 +301,9 @@ class TestDeviceIngest:
 
         ingest = DeviceIngest(1000, devices=[jax.devices()[0]])
         ingest.write(0, b"y" * 1000)   # completes the only shard
-        ingest._worker.join(5)
-        assert not ingest._worker.is_alive()
+        for w in ingest._workers:
+            w.join(5)
+        assert not any(w.is_alive() for w in ingest._workers)
         # result() still works after self-termination
         arrays = ingest.result(timeout=5)
         assert len(arrays) == 1
@@ -498,7 +502,8 @@ class TestSinkBufferPool:
             assert np.array_equal(arr.view(np.uint8).reshape(-1),
                                   padded[off:off + size])
         # every shard shipped: the whole 4096-byte buffer is parked again
-        di._worker.join(5)
+        for w in di._workers:
+            w.join(5)
         assert di.host is None and pool.parked_bytes() == 4096
 
     def test_a_smaller_lease_hits_a_larger_parked_buffer_best_fit(self):
@@ -546,8 +551,9 @@ class TestSinkBufferPool:
         addr = lease.address(0, 1000)
         assert addr == di.host.ctypes.data
         di.close()
-        di._worker.join(5)
-        assert not di._worker.is_alive()
+        for w in di._workers:
+            w.join(5)
+        assert not any(w.is_alive() for w in di._workers)
         assert di.host is not None and pool.parked_bytes() == 0
         lease.copy(0, b"z" * 1000)            # the landing finishes its copy
         assert lease.error is None and bytes(di.host) == b"z" * 1000
@@ -593,7 +599,8 @@ class TestSinkBufferPool:
         di.write(0, b"x" * 100)
         with pytest.raises(RuntimeError):
             di.result(timeout=10)
-        di._worker.join(5)
+        for w in di._workers:
+            w.join(5)
         assert di.host is None and pool.parked_bytes() == 0
 
     def test_nothing_is_recycled_under_a_live_array_on_the_cpu_backend(self):
@@ -616,7 +623,8 @@ class TestSinkBufferPool:
             di = DeviceIngest(n, devices=[dev], shard_specs=specs, pool=pool)
             di.write(0, raw)
             out = di.result(timeout=30)
-            di._worker.join(5)
+            for w in di._workers:
+                w.join(5)
             return raw, di, out
 
         raw1, di1, first = task(1)
@@ -691,3 +699,221 @@ class TestSinkBufferPool:
         assert pool._leased_bytes == 0
         assert pool.parked_bytes() == sum(b.nbytes for b in pool._parked)
         assert pool.parked_bytes() <= pool._leased_peak
+
+
+# ----------------------------------------------------------------------
+# placement by manifest, one transfer worker a chip
+# ----------------------------------------------------------------------
+
+class TestPlacementAndPerChipWorkers:
+    N = 16                                   # specs, 64 bytes each
+
+    def _specs(self, devices_of):
+        return [(f"t{i}", i * 64, 64, "uint8", None, devices_of(i))
+                for i in range(self.N)]
+
+    def _feed(self, di) -> bytes:
+        raw = _source(self.N * 64, seed=7)
+        for off in range(0, len(raw), 96):   # boundaries inside pieces
+            di.write(off, raw[off:off + 96])
+        return raw
+
+    def test_a_placed_manifest_puts_every_array_on_the_named_device(self):
+        import jax
+        devices = jax.devices()
+        assert len(devices) == 8             # tests/conftest.py's mesh
+        # an expert's three matrices together, four experts a chip
+        where = lambda i: (i // 3) % 4       # noqa: E731
+        di = DeviceIngest(self.N * 64, devices=devices,
+                          shard_specs=self._specs(where))
+        assert sorted(di._queues) == [0, 1, 2, 3]       # a worker a chip
+        raw = self._feed(di)
+        res = di.result(timeout=10)
+        for i in range(self.N):
+            arr = res[f"t{i}"]
+            assert arr.devices() == {devices[where(i)]}
+            assert bytes(np.asarray(arr)) == raw[i * 64:(i + 1) * 64]
+        # the chip of each span is kept beside it
+        assert len(di.transfer_chips) == len(di.transfer_spans) == self.N
+        per_chip = {}
+        for chip, nbytes in di.transfer_chips:
+            per_chip[chip] = per_chip.get(chip, 0) + nbytes
+        assert per_chip == {c: 64 * sum(1 for i in range(self.N)
+                                        if where(i) == c) for c in range(4)}
+
+    @pytest.mark.parametrize("specs", ["five_fields", "minus_one", "none"])
+    def test_an_unplaced_manifest_lands_exactly_where_it_went_before(
+            self, specs):
+        """Round-robin by spec index over every device of the sink, however
+        "unplaced" is spelt: no sixth element, -1, or None."""
+        import jax
+        devices = jax.devices()
+        full = self._specs(lambda i: {"minus_one": -1, "none": None,
+                                      "five_fields": 0}[specs])
+        if specs == "five_fields":
+            full = [sp[:5] for sp in full]
+        di = DeviceIngest(self.N * 64, devices=devices, shard_specs=full)
+        self._feed(di)
+        res = di.result(timeout=10)
+        for i in range(self.N):
+            assert res[f"t{i}"].devices() == {devices[i % len(devices)]}
+        assert [c for c, _n in sorted(di.transfer_chips)] == sorted(
+            i % 8 for i in range(self.N))
+
+    def test_placed_and_unplaced_specs_mix_in_one_manifest(self):
+        import jax
+        devices = jax.devices()[:4]
+        di = DeviceIngest(256, devices=devices, shard_specs=[
+            ("a", 0, 64, "uint8", None, 3), ("b", 64, 64),
+            ("c", 128, 64, "uint8", None, 3), ("d", 192, 64)])
+        di.write(0, bytes(256))
+        res = di.result(timeout=10)
+        assert [next(iter(res[n].devices())) for n in "abcd"] == [
+            devices[3], devices[1], devices[3], devices[3]]
+
+    def test_an_ordinal_the_sink_lacks_is_refused_at_construction(self):
+        import jax
+        pool = SinkBufferPool()
+        for bad in (4, 99, -2):
+            with pytest.raises(ValueError, match=f"device {bad}"):
+                DeviceIngest(128, devices=jax.devices()[:4], pool=pool,
+                             shard_specs=[("a", 0, 64, "uint8", None, 1),
+                                          ("b", 64, 64, "uint8", None, bad)])
+        # refused before a buffer was leased or a thread started
+        assert pool._leased_bytes == 0
+
+    def test_four_chips_transfers_overlap(self):
+        """Each transfer takes 50 ms: 16 of them on four chips' workers
+        take about four in a row, far under the sum."""
+        def slow_put(view, device):
+            time.sleep(0.05)
+            return np.array(view, copy=True)
+
+        di = DeviceIngest(self.N * 64, devices=[object()] * 4,
+                          device_put_fn=slow_put,
+                          shard_specs=self._specs(lambda i: i % 4))
+        t0 = time.monotonic()
+        self._feed(di)
+        di.result(timeout=10)
+        wall = time.monotonic() - t0
+        busy = sum(e - s for s, e in di.transfer_spans)
+        assert busy >= self.N * 0.05
+        assert wall < busy / 2, (wall, busy)
+        # and one chip's transfers never overlap each other: one worker
+        by_chip: dict = {}
+        for (s, e), (chip, _n) in zip(di.transfer_spans, di.transfer_chips):
+            by_chip.setdefault(chip, []).append((s, e))
+        for spans in by_chip.values():
+            spans.sort()
+            assert all(a[1] <= b[0] for a, b in zip(spans, spans[1:]))
+
+    def test_a_slow_chip_holds_up_only_its_own_queue(self):
+        import threading
+
+        gate = threading.Event()
+
+        def put(view, device):
+            if device == "slow":
+                assert gate.wait(10)
+            return np.array(view, copy=True)
+
+        done: list[str] = []
+        di = DeviceIngest(self.N * 64, devices=["slow", "b", "c", "d"],
+                          device_put_fn=put,
+                          shard_specs=self._specs(lambda i: i % 4),
+                          on_shard_ready=lambda n, _t: done.append(n))
+        self._feed(di)
+        deadline = time.monotonic() + 5
+        while len(done) < 12 and time.monotonic() < deadline:
+            threading.Event().wait(0.01)
+        # the three other chips are through; chip 0's four wait
+        assert sorted(done) == sorted(f"t{i}" for i in range(self.N)
+                                      if i % 4)
+        gate.set()
+        assert len(di.result(timeout=10)) == self.N
+
+    def test_one_chips_error_fails_the_task_while_the_others_drain(self):
+        """Chip 2's first transfer raises. The task fails with that error,
+        every other transfer still runs (the other chips' queues drain,
+        and chip 2's own), and the host buffer goes back, unrecycled, only
+        when the last worker is done."""
+        import threading
+
+        pool = SinkBufferPool()
+        gate = threading.Event()
+        put_on: list = []
+
+        def put(view, device):
+            put_on.append(device)
+            if device == 2 and put_on.count(2) == 1:
+                raise RuntimeError("chip 2 lost (test)")
+            if device == 3:
+                assert gate.wait(10)         # chip 3 is slow
+            return np.array(view, copy=True)
+
+        di = DeviceIngest(self.N * 64, devices=[0, 1, 2, 3],
+                          device_put_fn=put, pool=pool,
+                          shard_specs=self._specs(lambda i: i % 4))
+        self._feed(di)
+        deadline = time.monotonic() + 5
+        while len(put_on) < 13 and time.monotonic() < deadline:
+            threading.Event().wait(0.01)
+        # chips 0, 1 and 2 went through all four of theirs; chip 3 is
+        # inside its first
+        assert sorted(put_on) == [0] * 4 + [1] * 4 + [2] * 4 + [3]
+        di.close()
+        for w in di._workers[:3]:
+            w.join(5)
+        assert [w.is_alive() for w in di._workers] == [False, False, False,
+                                                       True]
+        # three workers gone, one still reading the buffer: it stays
+        assert di.host is not None
+        gate.set()
+        with pytest.raises(RuntimeError, match="device transfer failed"):
+            di.result(timeout=10)
+        di._workers[3].join(5)
+        assert len(put_on) == self.N and len(di.transfer_spans) == 15
+        assert di.host is None and pool.parked_bytes() == 0
+        assert pool._leased_bytes == 0       # released, and not recycled
+
+    def test_the_buffer_is_parked_when_the_last_chips_transfer_is_done(self):
+        import threading
+
+        pool = SinkBufferPool()
+        gate = threading.Event()
+
+        def put(view, device):
+            if device == 3:
+                assert gate.wait(10)
+            return np.array(view, copy=True)
+
+        di = DeviceIngest(256, devices=[0, 1, 2, 3], device_put_fn=put,
+                          pool=pool, shard_specs=[
+                              (f"t{i}", i * 64, 64, "uint8", None, i)
+                              for i in range(4)])
+        di.write(0, bytes(256))
+        deadline = time.monotonic() + 5
+        while len(di.transfer_spans) < 3 and time.monotonic() < deadline:
+            threading.Event().wait(0.01)
+        assert len(di.transfer_spans) == 3
+        assert di.host is not None and pool.parked_bytes() == 0
+        gate.set()
+        di.drain(timeout=10)
+        assert di.host is None and pool.parked_bytes() == 256
+        for w in di._workers:                # every worker self-terminates
+            w.join(5)
+        assert not any(w.is_alive() for w in di._workers)
+
+    def test_whole_buffer_mode_gets_a_worker_a_device_too(self):
+        import jax
+        devices = jax.devices()[:4]
+        di = DeviceIngest(4096, devices=devices, shards_per_device=2)
+        assert sorted(di._queues) == [0, 1, 2, 3]
+        raw = _source(4096, seed=3)
+        di.write(0, raw)
+        arrays = di.result(timeout=10)
+        assert [next(iter(a.devices())) for a in arrays] == [
+            d for d in devices for _ in range(2)]
+        assert b"".join(bytes(np.asarray(a)) for a in arrays) == raw
+        assert sorted(c for c, _n in di.transfer_chips) == [0, 0, 1, 1, 2, 2,
+                                                            3, 3]

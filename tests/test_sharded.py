@@ -62,6 +62,37 @@ class TestShardMath:
         validate_manifest([mk("a", 0, 4), mk("b", 100, 4)],
                           content_length=104)
 
+    def test_a_placement_is_a_chips_ordinal_or_minus_one(self):
+        assert mk("a", 0, 4).device == -1    # unplaced unless it says
+        validate_manifest([mk("a", 0, 4, device=-1), mk("b", 4, 4, device=0),
+                           mk("c", 8, 4, device=3)])
+        with pytest.raises(ValueError, match="device -2"):
+            validate_manifest([mk("a", 0, 4, device=-2)])
+
+    def test_the_placement_rides_the_wire_and_dfgets_manifest_file(
+            self, tmp_path):
+        import json
+
+        from dragonfly2_tpu.idl import base
+        from dragonfly2_tpu.idl.messages import (DownloadRequest,
+                                                 ShardManifest)
+        from dragonfly2_tpu.tools.dfget import _load_shard_manifest
+
+        req = DownloadRequest(url="u", shard_manifest=ShardManifest(
+            shards=[mk("a", 0, 4, device=2), mk("b", 4, 4)]))
+        got = base.loads(base.dumps(req)).shard_manifest.shards
+        assert [(s.name, s.device) for s in got] == [("a", 2), ("b", -1)]
+        # a sender from before the field: unplaced
+        raw = base.encode(req)
+        del raw["shard_manifest"]["shards"][0]["device"]
+        assert base.decode(raw).shard_manifest.shards[0].device == -1
+        path = tmp_path / "m.json"
+        path.write_text(json.dumps({"shards": [
+            {"name": "a", "range_start": 0, "range_size": 4, "device": 3},
+            {"name": "b", "range_start": 4, "range_size": 4}]}))
+        assert [s.device for s in
+                _load_shard_manifest(str(path)).shards] == [3, -1]
+
     def test_pieces_for_shards_boundary_mid_piece(self):
         # piece size 4: shard b straddles pieces 1 and 2 — both claimed
         shards = [mk("b", 6, 4)]

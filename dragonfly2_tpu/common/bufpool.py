@@ -12,7 +12,18 @@ Contract (the reuse-safety rules the pool's consumers live by):
 
 * ``acquire(size)`` returns a bytearray of EXACTLY ``size`` bytes, possibly
   dirty — callers must overwrite every byte they later read (the
-  downloader's short/long-read checks already guarantee a full fill).
+  downloader hands a buffer on only when the body it asked for, announced
+  at exactly ``size`` bytes, has been received to its last byte).
+* One holder besides the owner may keep a ``memoryview`` of a pooled
+  buffer, and only for the length of one read: the connection the
+  downloader receives the body on (``piece_downloader._Conn``), whose
+  transport writes each read straight into the buffer. It lets go when
+  the body is complete, before the buffer is handed on; and on every
+  other exit (deadline, cancel, error, short or long read) it drops the
+  view FIRST, aborts its transport (never reused), and only then does
+  ``_read_body`` release the buffer — all on the loop's thread, in that
+  order, so no late byte can be written into a buffer that is already
+  another download's.
 * ``release(buf)`` parks the buffer for reuse. The caller promises that no
   consumer still references its memory: the storage write has returned,
   and with it the staging copy into a device sink, which the landing makes
@@ -24,7 +35,9 @@ Contract (the reuse-safety rules the pool's consumers live by):
   NOT recycled: release probes with a resize (append+pop), which raises
   ``BufferError`` iff exports exist, and such buffers are discarded
   (counted ``df_bufpool_discards_total{reason="exported"}``) — a leaked
-  view can therefore never observe another download's bytes.
+  view can therefore never observe, or write into, another download's
+  bytes. For the receive path above this probe is the second line, not
+  the first.
 
 Buffers are keyed by exact size (piece geometry is uniform per task, so
 exact-size buckets hit ~always); the pool is bounded by total parked bytes

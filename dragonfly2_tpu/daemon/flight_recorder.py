@@ -11,7 +11,7 @@ with parent peer id, source (p2p vs back-to-source), and byte counts, and
 can summarize a finished task (slowest-piece attribution, per-parent
 throughput, tail-latency breakdown, back-to-source ratio). Beside the
 lifecycle it carries the *sections* of the data path, each with the
-seconds it ran (``dur_ms``): the loop's own copy off the wire
+seconds it ran (``dur_ms``): the wire's own callbacks on the loop
 (``wire_copy``), the storage thread's landing pass (``landed``), the
 staging copy it makes in the same hop (``staged``) and how long the
 landing waited for a thread and for the loop (``land_wait``), the sink's
@@ -68,9 +68,13 @@ HBM_DONE = "hbm_done"        # piece staged for the device sink and
 # data-path sections: each carries the seconds it ran in dur_ms, so a
 # summary can say where wire_done -> hbm_done went and the benchmark can
 # add the loop thread's own sections up against its CPU seconds
-WIRE_COPY = "wire_copy"      # one per dispatch: the seconds _read_body ran
-# ON the loop (per-chunk copy + watermark store, awaits excluded),
-# bytes = bytes read; the chunk count adds to TaskFlight.wire_chunks
+WIRE_COPY = "wire_copy"      # one per dispatch: the seconds the wire's own
+# callbacks ran ON the loop for its answer (piece_downloader._Conn:
+# get_buffer, buffer_updated, the head's parse and the copy of the few
+# body bytes that rode in behind the head; the kernel's receive and the
+# loop's wake-ups excluded), bytes = bytes read; the reads it took add to
+# TaskFlight.wire_chunks, the body bytes the kernel wrote straight into
+# the pooled buffer to TaskFlight.wire_direct_bytes
 LANDED = "landed"            # one per landing: the storage thread's write +
 # verify pass (t_ms = when the thread began, parent = the landing path:
 # native / python / per_piece, piece = the span's first piece)
@@ -166,7 +170,8 @@ class TaskFlight:
     __slots__ = ("task_id", "peer_id", "started_at", "_m0", "events",
                  "serves", "state", "url", "report_drops", "_sum_key",
                  "_sum_cache", "qos_class", "tenant", "shards_total",
-                 "on_rung", "fail_reason", "wire_chunks")
+                 "on_rung", "fail_reason", "wire_chunks",
+                 "wire_direct_bytes")
 
     def __init__(self, task_id: str, peer_id: str, *, url: str = "",
                  max_events: int = 4096, max_serves: int = 1024,
@@ -198,9 +203,12 @@ class TaskFlight:
         # (0 = not sharded) — set by the conductor so the summary's
         # shards block can report ready/total without replaying events
         self.shards_total = 0
-        # body chunks the wire handed the loop (one wake-up and one slice
-        # copy each): bytes_p2p over this is the chunk size a GiB rides in
+        # reads the wire took (one wake-up of the loop each): bytes_p2p
+        # over this is the read size a GiB rides in; and of those bytes,
+        # the ones received in place in the pooled buffer (the rest rode
+        # in behind a response head and were copied once)
         self.wire_chunks = 0
+        self.wire_direct_bytes = 0
         self._sum_key: tuple | None = None   # summarize() memo (see there)
         self._sum_cache: dict = {}
         # daemon-wide rung tally hook (FlightRecorder._note_rung): the
@@ -485,12 +493,13 @@ class TaskFlight:
             "hbm_dma_ms": round(hbm_dma_ms, 3),
             # data-path sections by kind (ms summed over the task), the
             # summed ``stage_ms`` among them (``stage_copy``: the name from
-            # when the copy ran there), and the wire's chunk count
+            # when the copy ran there), and the wire's read and direct-byte counts
             "sections_ms": {
                 **{k: round(v, 3) for k, v in sections.items()},
                 "stage_copy": round(sum(r["stage_ms"]
                                         for r in piece_rows), 3)},
             "wire_chunks": self.wire_chunks,
+            "wire_direct_bytes": self.wire_direct_bytes,
             # the degradation-ladder trail and the rung the task ended on —
             # dfdiag's verdict names it so "why did this go to origin"
             # never needs log spelunking

@@ -304,12 +304,13 @@ class PieceEngine:
     @staticmethod
     def _journal_wire(flight, num: int, parent_id: str, nbytes: int,
                       meta: dict) -> None:
-        """One dispatch's ``wire_copy``: what ``_read_body`` left in the
-        meta dict that rode the download."""
+        """One dispatch's ``wire_copy``, and its reads and direct bytes:
+        what ``_read_body`` left in the meta dict that rode the download."""
         if flight is not None:
             flight.event(fr.WIRE_COPY, num, parent_id, nbytes,
                          dur_ms=meta.get("copy_s", 0.0) * 1000.0)
             flight.wire_chunks += meta.get("chunks", 0)
+            flight.wire_direct_bytes += meta.get("direct", 0)
 
     async def _pull_single(self, conductor, session, single) -> bool:
         info: PieceInfo = single.piece_info

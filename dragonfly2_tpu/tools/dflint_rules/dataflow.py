@@ -128,7 +128,14 @@ class PooledBufferLifecycle(Rule):
       ``finally`` — those are the two blessed shapes.
     * **retention** — parking the buffer on ``self`` or in a closure
       outlives the release decision and is how a "freed" buffer grows a
-      second owner (the never-retain rule PR 5 wrote in prose).
+      second owner (the never-retain rule PR 5 wrote in prose). The one
+      sanctioned second holder is the connection ``_read_body`` receives
+      the body on: it is PASSED the buffer (a call argument, which this
+      rule does not read as retention) and parks a view of it for the
+      length of that one read, so the transport can write into it; it
+      drops the view when the body is complete, and on every failure
+      before ``_read_body`` releases — the order its ``except`` arm
+      spells out (connection closed, span retired, then release).
     * **use-after-release** — touching the buffer after ``release``
       reads ANOTHER download's bytes; the pool's export-probe catches
       live memoryviews but a plain reference sails through.

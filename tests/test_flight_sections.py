@@ -347,27 +347,40 @@ def test_annotate_is_the_profilers_span_only_where_jax_already_is():
 
 
 def test_read_body_counts_chunks_and_times_only_its_own_work():
-    class Content:
-        def __init__(self, chunks):
-            self.chunks = chunks
+    from test_piece_wire import CONTENT, StubParent, head
 
-        async def iter_any(self):
-            for c in self.chunks:
-                await asyncio.sleep(0.02)    # the wire: not the copy
-                yield c
-
-    chunks = [b"a" * 1000, b"b" * 3000, b"c" * 96]
-    resp = types.SimpleNamespace(content=Content(chunks))
-    meta: dict = {}
     from dragonfly2_tpu.common.bufpool import POOL
+    from dragonfly2_tpu.idl.messages import PieceInfo
 
-    buf = asyncio.run(PieceDownloader._read_body(
-        resp, 4096, "test", meta=meta))
+    parts = [1000, 3000, 96]
+
+    async def three_parts(req, writer):
+        body, off = req.body(), 0
+        writer.write(head(206, req.size))
+        for n in parts:
+            await writer.drain()
+            await asyncio.sleep(0.02)        # the wire: not this module
+            writer.write(body[off:off + n])
+            off += n
+
+    async def main():
+        async with StubParent(three_parts) as stub:
+            dl = PieceDownloader(timeout_s=10)
+            meta: dict = {}
+            buf, _cost = await dl.download_piece(
+                dst_addr=stub.addr, task_id="t" * 64, src_peer_id="me",
+                piece=PieceInfo(piece_num=0, range_start=0,
+                                range_size=4096), meta=meta)
+            await dl.close()
+            return buf, meta
+
+    buf, meta = asyncio.run(main())
     try:
-        assert bytes(buf[:4096]) == b"".join(chunks)
+        assert bytes(buf) == CONTENT[:4096]
     finally:
         POOL.release(buf)
-    assert meta["chunks"] == 3
+    assert meta["chunks"] == 4               # the head's read, then three
+    assert meta["direct"] == 4096            # received where they land
     assert 0 <= meta["copy_s"] < 0.02        # three sleeps would be 0.06
 
 
